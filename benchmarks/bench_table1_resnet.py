@@ -98,4 +98,7 @@ def run(csv_rows: list[str]) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run([])

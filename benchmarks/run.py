@@ -35,4 +35,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -6,6 +6,9 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# CI runs on the CPU backend, Pallas kernels in interpret mode; the chip path
+# is checked by chip_smoke.py on a TPU
+export JAX_PLATFORMS=cpu
 
 echo "== tier-1 test suite =="
 python -m pytest -x -q

@@ -1,95 +1,44 @@
-"""jax version-compatibility shims.
+"""Mesh helpers shared by the launchers, models and distributed code.
 
-The baked container pins jax 0.4.37 while parts of this codebase were written
-against the >= 0.6 API surface.  Everything version-gated lives here so the
-rest of the repo imports one stable spelling:
-
-* ``make_mesh(shapes, names)`` — newer jax grew an ``axis_types=`` kwarg and
-  ``jax.sharding.AxisType``; 0.4.37 has neither (every axis is implicitly
-  "auto"), so we only pass ``axis_types`` when the enum exists.
-* ``shard_map(...)`` — ``jax.shard_map`` with ``check_vma=`` / ``axis_names=``
-  on new jax; ``jax.experimental.shard_map.shard_map`` with ``check_rep=`` /
-  ``auto=`` (the complement of ``axis_names``) on 0.4.x.
+* ``make_mesh(shapes, names)`` — ``jax.make_mesh`` with every axis typed
+  ``Auto``, so sharding propagates through GSPMD as the rest of the code
+  expects (annotations are hints, not explicit-sharding types).
+* ``shard_map(...)`` — ``jax.shard_map`` with ``check_vma`` off by default and
+  ``axis_names`` (the mesh axes that are manual inside the body) taken as any
+  iterable.
 * ``manual_axis_names()`` — mesh axes that are Manual at the current trace
-  point (``jax.sharding.get_abstract_mesh`` on new jax; 0.4.x has no abstract
-  mesh, so nothing is ever reported Manual — matching its semantics, where
-  sharding constraints inside ``shard_map`` bodies are simply invalid and the
-  caller must avoid them by construction).
-* ``set_global_mesh(mesh)`` — ``jax.sharding.set_mesh`` when present, no-op
-  otherwise (0.4.x has no global mesh; explicit ``Mesh`` context managers and
-  ``NamedSharding`` cover the same programs).
+  point, read from ``jax.sharding.get_abstract_mesh``.
 """
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh", "shard_map", "manual_axis_names", "set_global_mesh"]
+__all__ = ["make_mesh", "shard_map", "manual_axis_names"]
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with every axis auto-typed, on any supported jax."""
-    axis_shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` with every axis auto-typed."""
     axis_names = tuple(axis_names)
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices, **kwargs)
+    return jax.make_mesh(tuple(axis_shapes), axis_names, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False,
               axis_names=None):
-    """Portable ``shard_map``.
-
-    ``axis_names`` (new-jax spelling) is the set of mesh axes that are manual
-    inside the body; on 0.4.x it becomes ``auto = mesh.axis_names - axis_names``.
-    ``check_vma`` maps onto 0.4.x's ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+    """``jax.shard_map``; ``axis_names`` is the set of mesh axes that are
+    manual inside the body (all of them when None)."""
     kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+                  check_vma=check_vma)
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    mapped = _shard_map(f, **kwargs)
-    if not kwargs.get("auto"):
-        return mapped
-
-    # 0.4.x's eager shard_map raises a bare NotImplementedError for auto axes;
-    # surface the actual requirement instead
-    def _jit_required(*args, **kw):
-        try:
-            return mapped(*args, **kw)
-        except NotImplementedError as e:
-            raise NotImplementedError(
-                "shard_map with axis_names= (partially-auto axes) only runs "
-                "under jax.jit on jax<0.5 — wrap the call in jax.jit") from e
-    return _jit_required
+        kwargs["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(f, **kwargs)
 
 
 def manual_axis_names() -> set:
     """Mesh axes that are Manual at the current trace point (may be empty)."""
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is None:
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or not am.axis_names:
         return set()
-    try:
-        am = get_abstract_mesh()
-        if am is None or not am.axis_names:
-            return set()
-        return {n for n, t in zip(am.axis_names, am.axis_types)
-                if t == jax.sharding.AxisType.Manual}
-    except Exception:
-        return set()
-
-
-def set_global_mesh(mesh) -> None:
-    """``jax.sharding.set_mesh`` when the running jax has a global mesh."""
-    if hasattr(jax.sharding, "set_mesh"):
-        jax.sharding.set_mesh(mesh)
+    return {n for n, t in zip(am.axis_names, am.axis_types)
+            if t == jax.sharding.AxisType.Manual}

@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["param_pspec", "params_pspecs", "batch_pspecs", "decode_state_pspecs",
-           "named", "mesh_axis_size", "plan_batch_spec"]
+           "named", "mesh_axis_size", "plan_batch_spec", "init_sharded"]
 
 
 def mesh_axis_size(mesh: Mesh, axis: str) -> int:
@@ -192,3 +192,14 @@ def decode_state_pspecs(state_tree: Any, mesh: Mesh):
 def named(mesh: Mesh, spec_tree):
     return jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), spec_tree,
                                   is_leaf=lambda x: isinstance(x, P))
+
+
+def init_sharded(init_fn, mesh: Mesh, pspecs_fn):
+    """Run the initializer ``init_fn()`` already partitioned: jit it with
+    ``out_shardings`` from ``pspecs_fn(abstract_tree, mesh)`` (e.g.
+    :func:`params_pspecs`, :func:`decode_state_pspecs`), so every device
+    materialises only its shard — a model larger than one device never
+    lands whole on the first one."""
+    abstract = jax.eval_shape(init_fn)
+    return jax.jit(init_fn,
+                   out_shardings=named(mesh, pspecs_fn(abstract, mesh)))()

@@ -7,7 +7,9 @@ VMEM — one HBM read + one write per weight, instead of read(norm) + read+write
 regularized weight matrix, so it is on the training hot path.
 
 Grid over row blocks; the full row (group) must fit one block — groups are
-matrix rows/columns (<= a few x 10^4 elements), comfortably inside VMEM.
+matrix rows/columns (<= a few x 10^4 elements).  The row block shrinks with
+the row length so one block stays within ``_BLOCK_BYTES``: input and output
+are double-buffered, so four blocks must fit the 16 MiB scoped VMEM default.
 """
 from __future__ import annotations
 
@@ -20,6 +22,8 @@ from jax.experimental import pallas as pl
 from .dispatch import resolve_interpret
 
 __all__ = ["group_prox"]
+
+_BLOCK_BYTES = 2 * 2**20
 
 
 def _kernel(a_ref, t_ref, o_ref):
@@ -49,7 +53,8 @@ def group_prox(
     for any G, which is what ``optim.prox_sgd`` needs for arbitrary layers.
     """
     g, m = a.shape
-    block_g = min(block_g, g)
+    rows_fit = max(8, _BLOCK_BYTES // (m * a.dtype.itemsize) // 8 * 8)
+    block_g = min(block_g, rows_fit, g)
     g_pad = ((g + block_g - 1) // block_g) * block_g
     ap = jnp.pad(a, ((0, g_pad - g), (0, 0))) if g_pad != g else a
     t = jnp.asarray(thresh, jnp.float32).reshape(1)

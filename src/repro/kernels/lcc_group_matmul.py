@@ -22,8 +22,9 @@ right-padded with identity factors, and narrow groups ride the shared D_pad
 with zero rows — the same invariants ``lcc_chain_matmul`` already relies on.
 
 Grid (G, b_blocks, E): slices innermost, so group g's output tile is revisited
-across e and accumulated in place; the chain-evaluation body is shared with
-``lcc_chain_matmul`` (``slice_axis=2``).
+across e and accumulated in place; both chain-evaluation bodies (interpreter
+gather, compiled one-hot) are shared with ``lcc_chain_matmul``
+(``slice_axis=2``), including the compiled body's batch-major re-layout.
 """
 from __future__ import annotations
 
@@ -35,7 +36,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .dispatch import record_launch, resolve_interpret
-from .lcc_chain_matmul import _kernel
+from .lcc_chain_matmul import (_gather_kernel, _onehot_kernel,
+                               _onehot_operands)
 
 __all__ = ["lcc_group_matmul"]
 
@@ -88,24 +90,39 @@ def _lcc_group_matmul(
     run_interpret = resolve_interpret(interpret)
     if use_gather is None:
         use_gather = run_interpret
-    grid = (g_groups, b_pad // block_b, e_slices)
-    return pl.pallas_call(
-        functools.partial(_kernel, p_factors=p_factors, s_terms=s_terms,
-                          n_pad=n_pad, d_pad=d_pad, first_width=first_width,
-                          use_gather=use_gather, slice_axis=2),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, None, p_factors, n_pad, s_terms),
-                         lambda g, b, e: (g, e, 0, 0, 0)),
-            pl.BlockSpec((None, None, p_factors, n_pad, s_terms),
-                         lambda g, b, e: (g, e, 0, 0, 0)),
-            pl.BlockSpec((None, None, p_factors, n_pad, s_terms),
-                         lambda g, b, e: (g, e, 0, 0, 0)),
-            pl.BlockSpec((None, None, d_pad, block_b),
-                         lambda g, b, e: (g, e, 0, b)),
-        ],
-        out_specs=pl.BlockSpec((None, n_pad, block_b), lambda g, b, e: (g, 0, b)),
-        out_shape=jax.ShapeDtypeStruct((g_groups, n_pad, b_pad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((d_pad, block_b), jnp.float32)],
+    if use_gather:
+        spec = pl.BlockSpec((None, None, p_factors, n_pad, s_terms),
+                            lambda g, b, e: (g, e, 0, 0, 0))
+        return pl.pallas_call(
+            functools.partial(_gather_kernel, p_factors=p_factors,
+                              s_terms=s_terms, n_pad=n_pad, slice_axis=2),
+            grid=(g_groups, b_pad // block_b, e_slices),
+            in_specs=[spec, spec, spec,
+                      pl.BlockSpec((None, None, d_pad, block_b),
+                                   lambda g, b, e: (g, e, 0, b))],
+            out_specs=pl.BlockSpec((None, n_pad, block_b),
+                                   lambda g, b, e: (g, 0, b)),
+            out_shape=jax.ShapeDtypeStruct((g_groups, n_pad, b_pad),
+                                           jnp.float32),
+            scratch_shapes=[pltpu.VMEM((d_pad, block_b), jnp.float32)],
+            interpret=run_interpret,
+        )(idx, exp, sign, x.astype(jnp.float32))
+    idx_t, coef_t, x_t, bb, bt_pad = _onehot_operands(idx, exp, sign, x,
+                                                     block_b)
+    spec = pl.BlockSpec((None, None, p_factors * s_terms, n_pad),
+                        lambda g, b, e: (g, e, 0, 0))
+    y = pl.pallas_call(
+        functools.partial(_onehot_kernel, p_factors=p_factors,
+                          s_terms=s_terms, n_pad=n_pad,
+                          first_width=first_width, d_pad=d_pad, slice_axis=2),
+        grid=(g_groups, bt_pad // bb, e_slices),
+        in_specs=[spec, spec,
+                  pl.BlockSpec((None, None, bb, d_pad),
+                               lambda g, b, e: (g, e, b, 0))],
+        out_specs=pl.BlockSpec((None, bb, n_pad), lambda g, b, e: (g, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((g_groups, bt_pad, n_pad), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bb, d_pad), jnp.float32),
+                        pltpu.VMEM((bb, n_pad), jnp.float32)],
         interpret=run_interpret,
-    )(idx, exp, sign, x.astype(jnp.float32))
+    )(idx_t, coef_t, x_t)
+    return jnp.swapaxes(y[:, :b_pad], 1, 2)

@@ -30,10 +30,10 @@ def _kernel(labels_ref, x_ref, o_ref, *, block_c: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    labels = labels_ref[...]  # [bk] int32
-    bk = labels.shape[0]
+    labels = labels_ref[...]  # [1, bk] int32
+    bk = labels.shape[1]
     clusters = jax.lax.broadcasted_iota(jnp.int32, (block_c, bk), 0) + c0
-    onehot = (labels[None, :] == clusters).astype(jnp.float32)  # [bc, bk]
+    onehot = (labels == clusters).astype(jnp.float32)  # [bc, bk]
     x = x_ref[...].astype(jnp.float32)  # [bk, bb]
     o_ref[...] += jnp.dot(onehot, x, preferred_element_type=jnp.float32)
 
@@ -61,10 +61,12 @@ def cluster_segment_sum(
         functools.partial(_kernel, block_c=block_c),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_k,), lambda i, j, p: (j,)),
+            # labels ride as a [1, K] row: a 1-D block would not match the
+            # tiling XLA gives a 1-D int32 operand on TPU
+            pl.BlockSpec((1, block_k), lambda i, j, p: (0, j)),
             pl.BlockSpec((block_k, block_b), lambda i, j, p: (j, p)),
         ],
         out_specs=pl.BlockSpec((block_c, block_b), lambda i, j, p: (i, p)),
         out_shape=jax.ShapeDtypeStruct((c, b), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(labels, x)
+    )(labels.reshape(1, k), x)

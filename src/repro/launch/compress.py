@@ -26,6 +26,7 @@ import time
 import jax
 
 from repro.core import CompressionConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_model(arch: str, quickstart: bool, seed: int):
@@ -50,7 +51,7 @@ def build_model(arch: str, quickstart: bool, seed: int):
     from repro.models import api
 
     cfg = get_arch(arch)
-    if quickstart or jax.default_backend() == "cpu":
+    if quickstart:
         cfg = reduced_config(cfg, vocab=64, n_layers=2, d_model=32, d_ff=48,
                              n_heads=2, n_kv_heads=2, head_dim=16)
     return api.init_params(jax.random.PRNGKey(seed), cfg), cfg
@@ -88,7 +89,7 @@ def main() -> None:
     ap.add_argument("--family", default=None,
                     help="expected architecture family (sanity check)")
     ap.add_argument("--quickstart", action="store_true",
-                    help="reduced quickstart-scale dims (default on CPU)")
+                    help="reduced quickstart-scale dims")
     ap.add_argument("--config", nargs="*", default=[], metavar="KEY=VAL",
                     help="CompressionConfig overrides, e.g. algorithm=fs")
     ap.add_argument("--budget", type=int, default=None,
@@ -107,6 +108,7 @@ def main() -> None:
     ap.add_argument("--metrics-out", default=None,
                     help="write the run's metrics snapshot as JSON at exit")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.models import api
     from repro.obs import MetricsRegistry, dump_metrics, get_global

@@ -33,7 +33,7 @@ from repro.configs import ARCHS, SHAPE_CELLS, cell_supported, input_specs
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.distributed import sharding
 from repro.distributed.act_shard import mesh_context
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import PRODUCTION_KIND, make_production_mesh, peaks
 from repro.models import api
 from repro.models import flops as aflops
 from repro.optim.optimizers import adamw
@@ -287,10 +287,11 @@ def run_cell(arch: str, shape: str, multi_pod: bool, do_cost: bool = True,
         rec["cost_points"] = snaps
         rec["cost"] = full
         # roofline terms (per-device seconds)
+        hw = peaks(PRODUCTION_KIND)
         rec["roofline"] = {
-            "compute_s": full["flops"] / HW.PEAK_FLOPS_BF16,
-            "memory_s": full["bytes"] / HW.HBM_BW,
-            "collective_s": full["coll_link_bytes"] / HW.ICI_BW,
+            "compute_s": full["flops"] / hw.flops_bf16,
+            "memory_s": full["bytes"] / hw.hbm_bw,
+            "collective_s": full["coll_link_bytes"] / hw.ici_bw,
         }
         mf = aflops.model_flops(cfg, cell)
         rec["model_flops_global"] = mf
@@ -299,7 +300,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, do_cost: bool = True,
         dom = max(rec["roofline"], key=rec["roofline"].get)
         rec["dominant"] = dom
         step_time = max(rec["roofline"].values())
-        rec["roofline_fraction"] = (mf / n_dev / HW.PEAK_FLOPS_BF16) / max(step_time, 1e-12)
+        rec["roofline_fraction"] = (mf / n_dev / hw.flops_bf16) / max(step_time, 1e-12)
     except Exception as e:
         rec["cost_error"] = f"{type(e).__name__}: {e}"
         rec["trace_cost"] = traceback.format_exc()[-2000:]

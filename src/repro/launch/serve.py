@@ -17,6 +17,8 @@ import repro.core as core
 from repro import compat, obs
 from repro.configs import get_arch, reduced_config
 from repro.data.synthetic import MarkovLM
+from repro.distributed import sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serving.engine import ServingEngine
 from repro.serving.scheduler import Scheduler
@@ -71,13 +73,20 @@ def main() -> None:
                     help="serve GET /metrics (Prometheus text) on this port "
                          "for the run's duration (0 = ephemeral)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.kernel and not args.compress:
         ap.error("--kernel routes a compressed artifact; pass --compress too")
 
     cfg = get_arch(args.arch)
-    if args.reduced or jax.default_backend() == "cpu":
+    if args.reduced:
         cfg = reduced_config(cfg, vocab=256)
-    params = api.init_params(jax.random.PRNGKey(0), cfg)
+    mesh = build_mesh(args.dp, args.tp)
+    if mesh is None:
+        params = api.init_params(jax.random.PRNGKey(0), cfg)
+    else:  # each device builds only its shard of the weights
+        params = sharding.init_sharded(
+            lambda: api.init_params(jax.random.PRNGKey(0), cfg), mesh,
+            sharding.params_pspecs)
     artifact = None
     if args.compress:
         artifact = api.compress_model(
@@ -96,11 +105,11 @@ def main() -> None:
         eng = ServingEngine(artifact=artifact, n_slots=args.slots, max_len=128,
                             temperature=args.temperature,
                             use_kernel=args.kernel,
-                            mesh=build_mesh(args.dp, args.tp), **kv)
+                            mesh=mesh, **kv)
     else:
         eng = ServingEngine(params, cfg, n_slots=args.slots, max_len=128,
                             temperature=args.temperature,
-                            mesh=build_mesh(args.dp, args.tp), **kv)
+                            mesh=mesh, **kv)
     registries = [obs.get_global(), eng.metrics]
     srv = None
     if args.metrics_port is not None:

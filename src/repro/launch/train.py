@@ -44,6 +44,7 @@ from repro.data.synthetic import MarkovLM
 from repro.distributed import sharding
 from repro.distributed.act_shard import mesh_context
 from repro.distributed.elastic import plan_for_devices, reshard_tree
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.optimizers import adamw, cosine_warmup, prox_sgd
 from repro.obs import MetricsRegistry, dump_metrics, get_global
 from repro.training.trainer import (TrainState, init_train_state,
@@ -278,6 +279,7 @@ def main() -> None:
     ap.add_argument("--metrics-out", default=None,
                     help="write the run's metrics snapshot as JSON at exit")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "mlp":
         return mlp_main(args)
@@ -285,7 +287,7 @@ def main() -> None:
     args.lr = 3e-3 if args.lr is None else args.lr
 
     cfg = get_arch(args.arch)
-    if args.reduced or jax.default_backend() == "cpu":
+    if args.reduced:
         cfg = reduced_config(cfg, vocab=256)
     mesh = build_mesh(args.mesh)
     if args.grad_compression and (mesh is None or "pod" not in mesh.shape):
@@ -341,7 +343,7 @@ def main() -> None:
     ctx = mesh_context(mesh)
     with ctx:
         if mesh is not None:
-            compat.set_global_mesh(mesh)
+            jax.sharding.set_mesh(mesh)
         t0 = time.time()
         i = start_step
         while i < args.steps:
@@ -385,7 +387,7 @@ def main() -> None:
                 mesh = new_mesh
                 args.grad_compression = False  # single pod left
                 step_fn = make_step(None)
-                compat.set_global_mesh(mesh)
+                jax.sharding.set_mesh(mesh)
                 from repro.distributed import act_shard
                 act_shard.set_mesh(mesh)  # activation constraints follow the new mesh
                 i += 1

@@ -100,12 +100,29 @@ _forkserver_preloaded = False
 _executors: dict[int, ProcessPoolExecutor] = {}
 
 
+def _worker_init() -> None:
+    """Pin every worker to the CPU backend before any job runs.
+
+    Slice jobs are numpy-only, but the job module imports jax.  The parent
+    usually holds the accelerator already (it built the model's params), and
+    an accelerator belongs to one process at a time, so a worker that
+    initialised the default backend would fail or hang on it.  Importing jax
+    initialises no backend, so pinning here, before the first job, holds for
+    the forkserver's preloaded workers as for spawned ones; the environment
+    variable carries the pin to anything a worker starts."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _make_executor(n_workers: int) -> ProcessPoolExecutor:
     """Worker pool on a forkserver context: the forkserver imports the job
     module (and its jax dependency chain) ONCE before any XLA threads exist
     in it, then every worker forks cheaply from that clean single-threaded
     process — avoiding both fork-from-threaded-jax deadlocks and a per-worker
-    jax re-import (spawn is the non-POSIX fallback)."""
+    jax re-import (spawn is the non-POSIX fallback).  Workers run on the CPU
+    backend (:func:`_worker_init`)."""
     global _forkserver_preloaded
     methods = multiprocessing.get_all_start_methods()
     if "forkserver" in methods:
@@ -115,7 +132,8 @@ def _make_executor(n_workers: int) -> ProcessPoolExecutor:
         ctx = multiprocessing.get_context("forkserver")
     else:
         ctx = multiprocessing.get_context("spawn")
-    return ProcessPoolExecutor(max_workers=n_workers, mp_context=ctx)
+    return ProcessPoolExecutor(max_workers=n_workers, mp_context=ctx,
+                               initializer=_worker_init)
 
 
 def _get_executor(n_workers: int) -> ProcessPoolExecutor:

@@ -131,14 +131,16 @@ class ServingEngine:
                 # shared prefixes as they wrap — both disable sharing
                 prefix_cache=(prefix_cache and cfg.pos in ("rope", "none")),
                 windowed=windowed)
-            self.state = api.init_decode_state(cfg, n_slots, max_len,
-                                               kv_block=kv_block,
-                                               kv_blocks=kv_blocks)
+            self.state = self._init_state(
+                lambda: api.init_decode_state(cfg, n_slots, max_len,
+                                              kv_block=kv_block,
+                                              kv_blocks=kv_blocks))
             self._pool_leaves = ("c_kv", "k_rope") if "c_kv" in self.state else ("k", "v")
             self._tbl_host = np.zeros((n_slots, mb), np.int32)
             self._extend_fns: dict[int, object] = {}
         else:
-            self.state = api.init_decode_state(cfg, n_slots, max_len)
+            self.state = self._init_state(
+                lambda: api.init_decode_state(cfg, n_slots, max_len))
         # host mirrors of the device-side per-slot control state
         self.pos = np.zeros(n_slots, np.int64)
         self.active = np.zeros(n_slots, bool)
@@ -227,6 +229,15 @@ class ServingEngine:
         self._fb_seen: set[str] = set()  # plan keys already counted
         self._step_fn = self._build_step_fn()
 
+    def _init_state(self, init_fn):
+        """The decode state, built directly in its mesh sharding when
+        serving on a mesh (never whole on one device first)."""
+        if self.mesh is None:
+            return init_fn()
+        from repro.distributed import sharding as shd
+
+        return shd.init_sharded(init_fn, self.mesh, shd.decode_state_pspecs)
+
     @staticmethod
     def _build_executor(artifact, interpret, mesh=None):
         """Site-keyed :class:`CompressedExecutor` over the artifact — family
@@ -298,7 +309,6 @@ class ServingEngine:
         self._param_sh = shd.named(self.mesh, shd.params_pspecs(self.params, self.mesh))
         self._state_sh = shd.named(self.mesh, shd.decode_state_pspecs(self.state, self.mesh))
         self.params = jax.device_put(self.params, self._param_sh)
-        self.state = jax.device_put(self.state, self._state_sh)
         rep = NamedSharding(self.mesh, P())
         # explicit shardings: prefill-time state surgery can't change the step
         # signature, so the step never re-traces on a sharding flip

@@ -722,7 +722,8 @@ class CompressedExecutor:
         manual-EP or shared-expert MoE, encoder-decoder, learned positions,
         non-f32 compute dtype, compiled TPU backend) falls back to the
         per-region grouped route, which covers every family.  Every fallback
-        records its reason in :attr:`plan_fallbacks`.
+        records its reason in :attr:`plan_fallbacks`; an eligible plan whose
+        build fails raises.
         """
         if not self.use_plans:
             self.plan_fallbacks.setdefault("step", self._disabled_reason)
@@ -731,15 +732,8 @@ class CompressedExecutor:
             reason = _plan_ineligible_reason(cfg, bool(self._matvecs))
             plan = None
             if reason is None:
-                try:
-                    plan = StepPlan(self, cfg)
-                except Exception as exc:  # defensive: plan failure must not
-                    import warnings  # kill decode
-
-                    warnings.warn(f"step plan build failed ({exc}); "
-                                  "falling back to per-region kernels")
-                    reason = f"build_error:{type(exc).__name__}"
-            if reason is not None:
+                plan = StepPlan(self, cfg)
+            else:
                 self.plan_fallbacks["step"] = reason
             self._plans["step"] = plan
         plan = self._plans["step"]
@@ -764,15 +758,8 @@ class CompressedExecutor:
                     != jnp.float32:
                 reason = "cdtype"
             else:
-                try:
-                    plan = MoEPlan(self, site_tag, n_experts=n_experts,
-                                   d_model=d_model, d_ff=d_ff)
-                except Exception as exc:
-                    import warnings
-
-                    warnings.warn(f"moe plan build failed ({exc}); "
-                                  "falling back to per-region kernels")
-                    reason = f"build_error:{type(exc).__name__}"
+                plan = MoEPlan(self, site_tag, n_experts=n_experts,
+                               d_model=d_model, d_ff=d_ff)
             if reason is not None:
                 self.plan_fallbacks[key] = reason
             self._plans[key] = plan

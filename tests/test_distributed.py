@@ -22,6 +22,8 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def _run_subprocess(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
+    # virtual host devices: these children run on the CPU backend by design
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -71,6 +73,16 @@ def test_heartbeat_monitor_flags_straggler():
     failed = mon.failed_pods(now=40.0)
     assert 1 in failed
     assert mon.surviving_device_count(512, failed) == 256
+
+
+def test_peaks_keyed_by_device_kind():
+    """Roofline peaks come from the chip's ``device_kind``; a kind without
+    published peaks is an error, never a default."""
+    from repro.launch.mesh import PRODUCTION_KIND, peaks
+
+    assert peaks(PRODUCTION_KIND).flops_bf16 == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
 
 
 # --------------------------------------------------------------- semantics

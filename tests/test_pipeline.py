@@ -242,7 +242,7 @@ def _cli_cmd(out_dir, *extra):
 
 
 def _cli_env():
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # a CPU compression run
     env["PYTHONPATH"] = os.path.join(REPO, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     return env
@@ -342,3 +342,21 @@ def test_drop_mode_stats_unchanged():
     res = run_pipeline(_units(n_dense=2), _cfg(), n_workers=1)
     assert res.stats["skipped_jobs"] == 0
     assert res.stats["shrunk_jobs"] == 0
+
+
+def test_workers_run_on_cpu_backend():
+    """Slice workers are pinned to the CPU backend: the parent may hold the
+    accelerator, which only one process can use at a time.  A fresh process
+    whose environment names no platform starts the pool, so the pin must
+    come from the worker start-up itself."""
+    code = ("import os, jax\n"
+            "from repro.pipeline import runner\n"
+            "ex = runner._make_executor(1)\n"
+            "print(ex.submit(os.getenv, 'JAX_PLATFORMS').result(timeout=120),"
+            " ex.submit(jax.default_backend).result(timeout=120))\n"
+            "ex.shutdown(wait=True)\n")
+    env = {k: v for k, v in _cli_env().items() if k != "JAX_PLATFORMS"}
+    r = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == ["cpu", "cpu"]
