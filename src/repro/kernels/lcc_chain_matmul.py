@@ -175,6 +175,7 @@ def lcc_chain_matmul(
     first_width: int | None = None,
     interpret: bool | None = None,
     use_gather: bool | None = None,
+    name: str = "_lcc_chain_matmul",
 ) -> jnp.ndarray:
     """y[N_pad, B_pad] = sum_e chain_e(x[e]) — whole decomposition, one launch.
 
@@ -184,15 +185,18 @@ def lcc_chain_matmul(
     ``use_gather``: force the decompress formulation (default: gather when
     interpreting, one-hot/MXU when compiled); exposed so the compiled
     formulation stays testable under the interpreter.
+    ``name``: the launch's name in the compiled program and the device trace
+    (the serving executor names each by its site group).
     """
     record_launch()  # un-jitted: counts once per pallas_call a trace emits
     return _lcc_chain_matmul(idx, exp, sign, x, block_b=block_b,
                              first_width=first_width, interpret=interpret,
-                             use_gather=use_gather)
+                             use_gather=use_gather, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "first_width",
-                                             "interpret", "use_gather"))
+                                             "interpret", "use_gather",
+                                             "name"))
 def _lcc_chain_matmul(
     idx: jnp.ndarray,
     exp: jnp.ndarray,
@@ -202,6 +206,7 @@ def _lcc_chain_matmul(
     first_width: int | None = None,
     interpret: bool | None = None,
     use_gather: bool | None = None,
+    name: str = "_lcc_chain_matmul",
 ) -> jnp.ndarray:
     e_slices, p_factors, n_pad, s_terms = idx.shape
     xe, d_pad, b_pad = x.shape
@@ -230,6 +235,7 @@ def _lcc_chain_matmul(
             out_shape=jax.ShapeDtypeStruct((n_pad, b_pad), jnp.float32),
             scratch_shapes=[pltpu.VMEM((d_pad, block_b), jnp.float32)],
             interpret=run_interpret,
+            name=name,
         )(idx, exp, sign, x.astype(jnp.float32))
     idx_t, coef_t, x_t, bb, bt_pad = _onehot_operands(idx, exp, sign, x,
                                                      block_b)
@@ -249,5 +255,6 @@ def _lcc_chain_matmul(
         scratch_shapes=[pltpu.VMEM((bb, d_pad), jnp.float32),
                         pltpu.VMEM((bb, n_pad), jnp.float32)],
         interpret=run_interpret,
+        name=name,
     )(idx_t, coef_t, x_t)
     return y[:b_pad].T

@@ -51,21 +51,24 @@ def lcc_group_matmul(
     first_width: int | None = None,
     interpret: bool | None = None,
     use_gather: bool | None = None,
+    name: str = "_lcc_group_matmul",
 ) -> jnp.ndarray:
     """y[G, N_pad, B_pad] = per-group sum_e chain_{g,e}(x[g, e]) — one launch.
 
     Same contract as :func:`~repro.kernels.lcc_chain_matmul.lcc_chain_matmul`
     per group; ``first_width`` is shared across groups (the max padded slice
     width — narrower groups read zero-padded columns, which contribute 0).
+    ``name``: the launch's name in the compiled program and the device trace.
     """
     record_launch()  # un-jitted: counts once per pallas_call a trace emits
     return _lcc_group_matmul(idx, exp, sign, x, block_b=block_b,
                              first_width=first_width, interpret=interpret,
-                             use_gather=use_gather)
+                             use_gather=use_gather, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "first_width",
-                                             "interpret", "use_gather"))
+                                             "interpret", "use_gather",
+                                             "name"))
 def _lcc_group_matmul(
     idx: jnp.ndarray,
     exp: jnp.ndarray,
@@ -75,6 +78,7 @@ def _lcc_group_matmul(
     first_width: int | None = None,
     interpret: bool | None = None,
     use_gather: bool | None = None,
+    name: str = "_lcc_group_matmul",
 ) -> jnp.ndarray:
     g_groups, e_slices, p_factors, n_pad, s_terms = idx.shape
     xg, xe, d_pad, b_pad = x.shape
@@ -106,6 +110,7 @@ def _lcc_group_matmul(
                                            jnp.float32),
             scratch_shapes=[pltpu.VMEM((d_pad, block_b), jnp.float32)],
             interpret=run_interpret,
+            name=name,
         )(idx, exp, sign, x.astype(jnp.float32))
     idx_t, coef_t, x_t, bb, bt_pad = _onehot_operands(idx, exp, sign, x,
                                                      block_b)
@@ -124,5 +129,6 @@ def _lcc_group_matmul(
         scratch_shapes=[pltpu.VMEM((bb, d_pad), jnp.float32),
                         pltpu.VMEM((bb, n_pad), jnp.float32)],
         interpret=run_interpret,
+        name=name,
     )(idx_t, coef_t, x_t)
     return jnp.swapaxes(y[:, :b_pad], 1, 2)

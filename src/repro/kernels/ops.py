@@ -247,8 +247,10 @@ def pack_group(members: list[PackedDecomposition]) -> PackedGroup:
 
 
 def apply_packed_group(pg: PackedGroup, xs, *, block: int = 128,
-                       interpret: bool | None = None) -> list[jnp.ndarray]:
-    """y_g = W_hat_g @ xs[g] for every group member — ONE fused launch.
+                       interpret: bool | None = None,
+                       name: str = "_lcc_group_matmul") -> list[jnp.ndarray]:
+    """y_g = W_hat_g @ xs[g] for every group member — ONE fused launch
+    (called ``name`` in the compiled program and the device trace).
 
     ``xs`` is a per-member list of [K_g, B] inputs (all the same B; K_g is the
     member's own in_dim — members need not agree on input width because each
@@ -276,7 +278,8 @@ def apply_packed_group(pg: PackedGroup, xs, *, block: int = 128,
             stacks.append(jnp.stack(slabs))
         y = lcc_group_matmul(pg.idx, pg.exp, pg.sign, jnp.stack(stacks),
                              block_b=bb, first_width=pg.first_width,
-                             interpret=interpret)  # [G, N_pad, B_pad]
+                             interpret=interpret,
+                             name=name)  # [G, N_pad, B_pad]
     outs = []
     for g, (m, x) in enumerate(zip(pg.members, xs)):
         yg = y[g, : m.out_dim, :b] if (y is not None and m.col_slices) else None
@@ -340,11 +343,13 @@ def apply_packed_chain(pc: PackedChain, x: jnp.ndarray, *, block: int = 128,
 
 def apply_packed_decomposition(packed: PackedDecomposition, x: jnp.ndarray, *,
                                block: int = 128, interpret: bool | None = None,
-                               fused: bool = True) -> jnp.ndarray:
+                               fused: bool = True,
+                               name: str = "_lcc_chain_matmul") -> jnp.ndarray:
     """y = W_hat @ x for a packed decomposition; x [K, B] (or [K] vector).
 
     All FP slices run in a single ``lcc_chain_matmul`` launch (``fused=True``,
-    the default); ``fused=False`` runs the legacy one-``pallas_call``-per-factor
+    the default; called ``name`` in the compiled program and the device
+    trace); ``fused=False`` runs the legacy one-``pallas_call``-per-factor
     loop for comparison.  Dense-fallback slices (FS programs) are added on top.
     """
     squeeze = x.ndim == 1
@@ -365,7 +370,7 @@ def apply_packed_decomposition(packed: PackedDecomposition, x: jnp.ndarray, *,
         if fused:
             y = lcc_chain_matmul(packed.idx, packed.exp, packed.sign, x_pad,
                                  block_b=bb, first_width=packed.first_width,
-                                 interpret=interpret)
+                                 interpret=interpret, name=name)
         else:
             y = _apply_stacked_per_factor(packed.idx, packed.exp, packed.sign,
                                           x_pad, packed.chain_lengths,

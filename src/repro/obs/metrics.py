@@ -90,9 +90,13 @@ class Counter(_Metric):
     def inc(self, n: float = 1.0, **labels) -> None:
         if n < 0:
             raise ValueError(f"{self.name}: counters only go up (n={n})")
-        k = self._key(labels)
+        self.inc_values(self._key(labels), n)
+
+    def inc_values(self, key: tuple, n: float) -> None:
+        """``inc`` by the label values in declared order, for a hot path
+        that resolved them once (no label-name check; ``n`` >= 0)."""
         with self._lock:
-            self._vals[k] = self._vals.get(k, 0.0) + n
+            self._vals[key] = self._vals.get(key, 0.0) + n
 
 
 class Gauge(_Metric):

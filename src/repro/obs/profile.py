@@ -1,12 +1,9 @@
 """Step profiling and the live roofline.
 
 :class:`StepProfiler` is a bounded wall-time ring buffer for the engine's
-fused decode step.  Host wall-clock alone under-reports async dispatch, so
-every ``fence_every``-th sample the profiler calls ``jax.block_until_ready``
-on the value the caller hands it *before* reading the clock — those samples
-carry the true device latency while the rest stay free.  (The serving engine
-already syncs each step when it pulls sampled tokens to host, so every sample
-is honest there; the fencing matters for callers that keep steps in flight.)
+fused decode step.  The caller's clock must already include the device work:
+the serving engine reads its sampled tokens back inside the timed region,
+which syncs every step.
 
 :func:`roofline` is the pure function behind ``BENCH_serving.json``'s
 roofline section: per-site shift-add budget from an artifact's
@@ -33,40 +30,28 @@ def _pct(sorted_vals, q: float) -> float | None:
 
 
 class StepProfiler:
-    """Ring buffer of per-step wall times with periodic device fencing.
+    """Ring buffer of per-step wall times.
 
     Usage (the engine's step loop)::
 
         t0 = prof.begin()
-        out = step_fn(...)
-        prof.end(t0, tokens=n_active, fence=out)
-
-    ``fence`` is only synced on every ``fence_every``-th sample; pass
-    ``fence=None`` to never sync (pure host timing).
+        out = np.asarray(step_fn(...))  # synced: the host reads the result
+        prof.end(t0, tokens=n_active)
     """
 
-    def __init__(self, capacity: int = 4096, fence_every: int = 32,
-                 clock=time.perf_counter):
+    def __init__(self, capacity: int = 4096, clock=time.perf_counter):
         self.capacity = int(capacity)
-        self.fence_every = max(0, int(fence_every))
         self.clock = clock
-        self._ring: deque = deque(maxlen=self.capacity)  # (wall_s, tokens, fenced)
+        self._ring: deque = deque(maxlen=self.capacity)  # (wall_s, tokens)
         self._n = 0          # lifetime samples (ring may have dropped old ones)
-        self._fenced = 0
 
     def begin(self) -> float:
         return self.clock()
 
-    def end(self, t0: float, tokens: int = 0, fence=None) -> float:
+    def end(self, t0: float, tokens: int = 0) -> float:
         self._n += 1
-        fenced = (fence is not None and self.fence_every
-                  and self._n % self.fence_every == 0)
-        if fenced:
-            import jax
-            jax.block_until_ready(fence)
-            self._fenced += 1
         dt = self.clock() - t0
-        self._ring.append((dt, int(tokens), fenced))
+        self._ring.append((dt, int(tokens)))
         return dt
 
     def __len__(self) -> int:
@@ -80,7 +65,7 @@ class StepProfiler:
         """Aggregates over the samples currently in the ring."""
         samples = list(self._ring)
         if not samples:
-            return {"steps": 0, "total_steps": self._n, "fenced": self._fenced,
+            return {"steps": 0, "total_steps": self._n,
                     "tok_s": None, "mean_ms": None, "p50_ms": None,
                     "p99_ms": None}
         walls = sorted(s[0] for s in samples)
@@ -89,7 +74,6 @@ class StepProfiler:
         return {
             "steps": len(samples),
             "total_steps": self._n,
-            "fenced": self._fenced,
             "tok_s": (total_tok / total_wall) if total_wall > 0 else None,
             "mean_ms": total_wall / len(walls) * 1e3,
             "p50_ms": _pct(walls, 0.50) * 1e3,

@@ -49,7 +49,8 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.kernels import dispatch
 from repro.models import api
-from repro.obs import MetricsRegistry, RequestTracer, StepProfiler
+from repro.obs import (MetricsRegistry, RequestTracer, StepProfiler,
+                       install_compile_counter, span)
 from repro.serving.executor import (CompressedExecutor, LCCMatvec,
                                     matvecs_from_artifact)
 from repro.serving.kvpool import KVPool, empty_stats
@@ -93,8 +94,7 @@ class ServingEngine:
                  use_kernel: bool = True, bulk_prefill: bool = True,
                  interpret: bool | None = None, mesh=None,
                  kv_block: int | None = 16, kv_blocks: int | None = None,
-                 prefix_cache: bool = True, metrics=None, tracer=None,
-                 fence_every: int = 32):
+                 prefix_cache: bool = True, metrics=None, tracer=None):
         if artifact is not None:
             if cfg is None:
                 cfg = artifact.config
@@ -176,8 +176,8 @@ class ServingEngine:
             self.metrics = None
         else:
             self.metrics = metrics if metrics is not None else MetricsRegistry()
-        self.profiler = (StepProfiler(fence_every=fence_every)
-                         if self.metrics is not None else None)
+        self.profiler = (StepProfiler() if self.metrics is not None
+                         else None)
         if tracer is True:
             self.tracer: RequestTracer | None = RequestTracer(
                 metrics=self.metrics)
@@ -221,11 +221,24 @@ class ServingEngine:
                 "serving_plan_fallbacks_total",
                 "layer-plan builds that fell back to the per-region route",
                 labels=("reason",))
+            # host seconds of each serving.* span, less its nested spans'
+            # (repro.obs.spans); the scheduler's spans publish here too
+            self.phases = m.counter(
+                "serving_phase_seconds_total",
+                "host wall seconds by program phase (own time)",
+                labels=("phase",))
+            self._m_prefill_tok = m.counter(
+                "serving_prefill_tokens_total",
+                "prefill positions: prompt tokens computed, prompt tokens "
+                "the prefix cache supplied, bucket padding computed",
+                labels=("kind",))
+            install_compile_counter()
         else:
             self._m_steps = self._m_tokens = self._m_step_hist = None
             self._m_prefills = self._m_prefill_hist = self._m_launches = None
             self._m_grown = self._m_exhausted = self._m_pool = None
             self._m_plans = self._m_plan_fallbacks = None
+            self.phases = self._m_prefill_tok = None
         self._fb_seen: set[str] = set()  # plan keys already counted
         self._step_fn = self._build_step_fn()
 
@@ -325,11 +338,6 @@ class ServingEngine:
         return max(self._trace_launches.values(), default=0)
 
     @property
-    def pallas_launches_by_bucket(self) -> dict:
-        """Per-trace launch counts keyed by decode input bucket ("BxT")."""
-        return dict(self._trace_launches)
-
-    @property
     def n_layer_plans(self) -> int:
         """Distinct layer plans the executor built for this engine."""
         if self.executor is None:
@@ -395,11 +403,14 @@ class ServingEngine:
         return s
 
     def submit(self, prompt: list[int], *, max_new: int | None = None,
-               temperature: float | None = None) -> int:
+               temperature: float | None = None,
+               sid: int | None = None) -> int:
         """Prefill a prompt into a free slot; returns request id.
 
         ``max_new`` / ``temperature`` override the engine defaults for this
         request only (the per-slot budget/temp arrays feed the fused step).
+        ``sid`` (the request tracer's span id, where the caller has one) is
+        carried on the admission's profiler spans beside the request id.
         """
         err = self.validate_prompt(prompt)
         if err is not None:
@@ -410,58 +421,70 @@ class ServingEngine:
         slot = int(free[0])
         rid = self._next_req
         self._next_req += 1
-        t_pre = time.perf_counter()
-        cached_tokens = 0
-        kind = "paged" if self.paged else (
-            "bulk" if self.bulk_prefill
-            and ("k" in self.state or "c_kv" in self.state) else "tokenwise")
-        if self.paged:
-            plan = self.pool.admit(slot, prompt)
-            if plan is None:
-                self._next_req -= 1
-                raise RuntimeError(
-                    f"insufficient free KV blocks for a {len(prompt)}-token "
-                    f"prompt ({self.pool.available_blocks} available); step() "
-                    "until a request finishes")
-            self._prefill_slot_paged(slot, prompt, plan)
-            self.pool.register_prefix(slot, prompt)
-            cached_tokens = plan.cached_tokens
-        elif kind == "bulk":
-            # one bulk forward writes the whole slot cache (and rewrites the
-            # full kpos row, so stale entries need no separate reset)
-            self._prefill_slot(slot, prompt)
-        else:
-            # stateful families (ssm/hybrid) keep the tokenwise path: their
-            # per-layer recurrent states live in scan-stacked layouts that a
-            # bulk forward does not expose per-slot; the slot column is reset
-            # first so the previous occupant's state/kpos never leaks
-            self._reset_slot_state(slot)
-            self._prefill_slot_tokenwise(slot, prompt)
-        self.pos[slot] = len(prompt)
-        self.active[slot] = True
-        self._last_tok[slot] = prompt[-1]
-        self._new_count[slot] = 0
-        self._max_new_arr[slot] = self.max_new if max_new is None else max_new
-        self._temp_arr[slot] = self.temp if temperature is None else temperature
-        # request-keyed PRNG: draws depend on (seed, rid, step), never on which
-        # slot the request landed in or what else is in flight
-        self._keys[slot] = np.asarray(
-            jax.random.fold_in(self._base_key, rid), np.uint32)
-        self._ctrl_dev = None  # budget/temp/key arrays changed: re-upload once
-        self._slot_dev = None  # host mirrors mutated: re-upload once
-        self.slot_req[slot] = rid
-        # host wall of the whole admission (dispatch + bookkeeping; the
-        # device work may still be in flight — bench paths that want the
-        # synced latency block on eng.state themselves)
-        prefill_s = time.perf_counter() - t_pre
-        if self._m_prefills is not None:
-            self._m_prefills.inc(1, kind=kind)
-            self._m_prefill_hist.observe(prefill_s)
-        self.results[rid] = GenerationResult(
-            tokens=list(prompt), prompt_len=len(prompt), finished=False,
-            stats={"prefill_s": prefill_s, "prefill_kind": kind,
-                   "cached_tokens": cached_tokens})
-        return rid
+        ids = {"rid": rid} if sid is None else {"rid": rid, "sid": sid}
+        with span("serving.submit", phases=self.phases, slot=slot,
+                  prompt_len=len(prompt), **ids) as sp:
+            t_pre = time.perf_counter()
+            cached_tokens = 0
+            kind = "paged" if self.paged else (
+                "bulk" if self.bulk_prefill
+                and ("k" in self.state or "c_kv" in self.state)
+                else "tokenwise")
+            if self.paged:
+                with span("serving.submit.pool", phases=self.phases, **ids):
+                    plan = self.pool.admit(slot, prompt)
+                if plan is None:
+                    self._next_req -= 1
+                    raise RuntimeError(
+                        f"insufficient free KV blocks for a {len(prompt)}-"
+                        f"token prompt ({self.pool.available_blocks} "
+                        "available); step() until a request finishes")
+                sp.set(cached=plan.cached_tokens)
+                self._prefill_slot_paged(slot, prompt, plan, ids)
+                with span("serving.submit.pool", phases=self.phases, **ids):
+                    self.pool.register_prefix(slot, prompt)
+                cached_tokens = plan.cached_tokens
+            elif kind == "bulk":
+                # one bulk forward writes the whole slot cache (and rewrites
+                # the full kpos row, so stale entries need no separate reset)
+                self._prefill_slot(slot, prompt, ids)
+            else:
+                # stateful families (ssm/hybrid) keep the tokenwise path:
+                # their per-layer recurrent states live in scan-stacked
+                # layouts that a bulk forward does not expose per-slot; the
+                # slot column is reset first so the previous occupant's
+                # state/kpos never leaks
+                self._reset_slot_state(slot)
+                with span("serving.submit.prefill", phases=self.phases, **ids):
+                    self._prefill_slot_tokenwise(slot, prompt)
+                self._count_prefill(len(prompt), 0, 0)
+            self.pos[slot] = len(prompt)
+            self.active[slot] = True
+            self._last_tok[slot] = prompt[-1]
+            self._new_count[slot] = 0
+            self._max_new_arr[slot] = (self.max_new if max_new is None
+                                       else max_new)
+            self._temp_arr[slot] = (self.temp if temperature is None
+                                    else temperature)
+            # request-keyed PRNG: draws depend on (seed, rid, step), never on
+            # which slot the request landed in or what else is in flight
+            self._keys[slot] = np.asarray(
+                jax.random.fold_in(self._base_key, rid), np.uint32)
+            self._ctrl_dev = None  # budget/temp/keys changed: re-upload once
+            self._slot_dev = None  # host mirrors mutated: re-upload once
+            self.slot_req[slot] = rid
+            # host wall of the whole admission (dispatch + bookkeeping; the
+            # device work may still be in flight — bench paths that want the
+            # synced latency block on eng.state themselves)
+            prefill_s = time.perf_counter() - t_pre
+            if self._m_prefills is not None:
+                self._m_prefills.inc(1, kind=kind)
+                self._m_prefill_hist.observe(prefill_s)
+            self.results[rid] = GenerationResult(
+                tokens=list(prompt), prompt_len=len(prompt), finished=False,
+                stats={"prefill_s": prefill_s, "prefill_kind": kind,
+                       "cached_tokens": cached_tokens})
+            return rid
 
     # -------------------------------------------------------------- prefill
     def _reset_slot_state(self, slot: int) -> None:
@@ -495,7 +518,15 @@ class ServingEngine:
                 self._token_batch(slot, tok), self._pos_batch(slot, t))
         self.state = self._merge_slot_state(old, scratch, slot)
 
-    def _prefill_slot(self, slot: int, prompt: list[int]) -> None:
+    def _count_prefill(self, prompt: int, cached: int, pad: int) -> None:
+        """One admission's prefill positions: prompt tokens the forward
+        computes, prompt tokens the prefix cache supplied, bucket padding."""
+        if self._m_prefill_tok is not None:
+            self._m_prefill_tok.inc(prompt, kind="prompt")
+            self._m_prefill_tok.inc(cached, kind="cached")
+            self._m_prefill_tok.inc(pad, kind="pad")
+
+    def _prefill_slot(self, slot: int, prompt: list[int], ids: dict) -> None:
         """Bulk prefill: ONE ``api.prefill`` forward over the prompt writes
         the slot's KV cache at its positions.  Prompts are right-padded to
         power-of-two buckets so recompilation is bounded (log2(max_len)
@@ -509,7 +540,10 @@ class ServingEngine:
                                          collect_cache=True))
         toks = np.zeros((1, s_pad), np.int32)
         toks[0, :plen] = prompt
-        _h, caches = self._prefill_fns[s_pad](self.params, jnp.asarray(toks))
+        with span("serving.submit.prefill", phases=self.phases, **ids):
+            _h, caches = self._prefill_fns[s_pad](self.params,
+                                                  jnp.asarray(toks))
+        self._count_prefill(plen, 0, s_pad - plen)
         st = dict(self.state)
         if "k" in st:
             k_all, v_all = caches  # [L, 1, S_pad, Hkv, Dh]
@@ -545,7 +579,8 @@ class ServingEngine:
         offs = vidx % bs
         st[name] = st[name].at[:, blocks, offs].set(vals.astype(st[name].dtype))
 
-    def _prefill_slot_paged(self, slot: int, prompt: list[int], plan) -> None:
+    def _prefill_slot_paged(self, slot: int, prompt: list[int], plan,
+                            ids: dict) -> None:
         """Apply an :class:`~repro.serving.kvpool.AdmitPlan`: install the
         block table row, device-copy the COW block, prefill only the
         non-cached tail (bulk forward when cold, ``api.prefill_extend``
@@ -556,12 +591,14 @@ class ServingEngine:
         bs, plen = pool.block_size, len(prompt)
         view = pool.view_blocks * bs  # == ring size when windowed
         tbl_row = plan.table
-        self._tbl_host[slot] = tbl_row
-        st["block_tbl"] = jnp.asarray(self._tbl_host)
+        with span("serving.submit.pool", phases=self.phases, **ids):
+            self._tbl_host[slot] = tbl_row
+            st["block_tbl"] = jnp.asarray(self._tbl_host)
         if plan.cow is not None:
             src, dst = plan.cow
-            for name in self._pool_leaves:
-                st[name] = st[name].at[:, dst].set(st[name][:, src])
+            with span("serving.submit.scatter", phases=self.phases, **ids):
+                for name in self._pool_leaves:
+                    st[name] = st[name].at[:, dst].set(st[name][:, src])
         cached = plan.cached_tokens
         kpos_row = np.full(view, -1, np.int64)
         if cfg.attn_window is not None:  # ring layout, no prefix sharing
@@ -572,26 +609,34 @@ class ServingEngine:
             ps = np.arange(cached, plen)
             vidx = ps
             kpos_row[:plen] = np.arange(plen)
-        if ps.size:  # uncached tail to prefill (cached == plen: nothing —
-            # the first decode step recomputes the last token's K/V anyway)
-            if cached == 0:
-                s_pad = min(self.max_len, max(8, 1 << (plen - 1).bit_length()))
-                if s_pad not in self._prefill_fns:
-                    self._prefill_fns[s_pad] = jax.jit(
-                        lambda p, t: api.prefill(p, cfg, {"tokens": t},
-                                                 collect_cache=True))
-                toks = np.zeros((1, s_pad), np.int32)
-                toks[0, :plen] = prompt
+        if not ps.size:  # cached == plen: nothing to prefill — the first
+            # decode step recomputes the last token's K/V anyway
+            self._count_prefill(0, cached, 0)
+        elif cached == 0:
+            s_pad = min(self.max_len, max(8, 1 << (plen - 1).bit_length()))
+            if s_pad not in self._prefill_fns:
+                self._prefill_fns[s_pad] = jax.jit(
+                    lambda p, t: api.prefill(p, cfg, {"tokens": t},
+                                             collect_cache=True))
+            toks = np.zeros((1, s_pad), np.int32)
+            toks[0, :plen] = prompt
+            with span("serving.submit.prefill", phases=self.phases, **ids):
                 _h, caches = self._prefill_fns[s_pad](self.params,
                                                       jnp.asarray(toks))
+            self._count_prefill(plen, 0, s_pad - plen)
+            with span("serving.submit.scatter", phases=self.phases, **ids):
                 for name, c_all in zip(self._pool_leaves, caches):
-                    self._scatter_pool(st, name, tbl_row, vidx, c_all[:, 0, ps])
-            else:
-                self._extend_tail(st, prompt, cached, tbl_row, vidx, view)
-        st["kpos"] = st["kpos"].at[:, slot].set(jnp.asarray(kpos_row, jnp.int32))
+                    self._scatter_pool(st, name, tbl_row, vidx,
+                                       c_all[:, 0, ps])
+        else:
+            self._extend_tail(st, prompt, cached, tbl_row, vidx, view, ids)
+        with span("serving.submit.scatter", phases=self.phases, **ids):
+            st["kpos"] = st["kpos"].at[:, slot].set(
+                jnp.asarray(kpos_row, jnp.int32))
         self.state = st
 
-    def _extend_tail(self, st, prompt, cached, tbl_row, vidx, view) -> None:
+    def _extend_tail(self, st, prompt, cached, tbl_row, vidx, view,
+                     ids: dict) -> None:
         """Prefix-hit tail prefill: gather the resident prefix through the
         block table (the exact contiguous view), run the tail tokens against
         it in one bucketed jitted forward, scatter the tail K/V back."""
@@ -606,21 +651,24 @@ class ServingEngine:
         toks[0, :tl] = prompt[cached:]
         posn = np.full((1, t_pad), -1, np.int64)
         posn[0, :tl] = np.arange(cached, plen)
-        past = {}
-        for name in self._pool_leaves:
-            pool_leaf = st[name]  # [L, Nb, bs, ...]
-            g = pool_leaf[:, tbl_row]  # gather: [L, mb, bs, ...]
-            past[name] = g.reshape(pool_leaf.shape[0], 1, view,
-                                   *pool_leaf.shape[3:])
-        pk = np.full((1, view), -1, np.int64)
-        pk[0, :cached] = np.arange(cached)
-        past["kpos"] = jnp.broadcast_to(
-            jnp.asarray(pk, jnp.int32)[None], (cfg.n_layers, 1, view))
-        _logits, tails = self._extend_fns[t_pad](
-            self.params, jnp.asarray(toks), jnp.asarray(posn, jnp.int32),
-            past, jnp.asarray([tl - 1], jnp.int32))
-        for name, tail in tails.items():  # [L, 1, t_pad, ...]
-            self._scatter_pool(st, name, tbl_row, vidx, tail[:, 0, :tl])
+        with span("serving.submit.prefill", phases=self.phases, **ids):
+            past = {}
+            for name in self._pool_leaves:
+                pool_leaf = st[name]  # [L, Nb, bs, ...]
+                g = pool_leaf[:, tbl_row]  # gather: [L, mb, bs, ...]
+                past[name] = g.reshape(pool_leaf.shape[0], 1, view,
+                                       *pool_leaf.shape[3:])
+            pk = np.full((1, view), -1, np.int64)
+            pk[0, :cached] = np.arange(cached)
+            past["kpos"] = jnp.broadcast_to(
+                jnp.asarray(pk, jnp.int32)[None], (cfg.n_layers, 1, view))
+            _logits, tails = self._extend_fns[t_pad](
+                self.params, jnp.asarray(toks), jnp.asarray(posn, jnp.int32),
+                past, jnp.asarray([tl - 1], jnp.int32))
+        self._count_prefill(tl, cached, t_pad - tl)
+        with span("serving.submit.scatter", phases=self.phases, **ids):
+            for name, tail in tails.items():  # [L, 1, t_pad, ...]
+                self._scatter_pool(st, name, tbl_row, vidx, tail[:, 0, :tl])
 
     def _release_slot(self, slot: int) -> None:
         """Return a retired slot's blocks to the pool (registered prefix
@@ -649,53 +697,69 @@ class ServingEngine:
         """One fused decode step for every active slot: exactly one jitted
         dispatch; the only device->host traffic is the packed [3, n_slots]
         (token, emit, done) array.  Returns this step's per-slot events."""
-        events: list[StepEvent] = []
         if not self.active.any():
-            return events
+            return []
+        with span("serving.step", phases=self.phases,
+                  step_num=self.step_dispatches):
+            return self._step()
+
+    def _step(self) -> list[StepEvent]:
+        events: list[StepEvent] = []
         if self.paged:
-            events.extend(self._grow_blocks())
+            with span("serving.step.grow", phases=self.phases):
+                events.extend(self._grow_blocks())
             if not self.active.any():
                 return events
         eos = np.int32(-1 if self.eos is None else self.eos)
-        if self._ctrl_dev is None:  # max_new/temps/keys only change at submit
-            self._ctrl_dev = (jnp.asarray(self._max_new_arr),
-                              jnp.asarray(self._temp_arr),
-                              jnp.asarray(self._keys))
+        if self._ctrl_dev is None or self._slot_dev is None:
+            with span("serving.step.upload", phases=self.phases):
+                if self._ctrl_dev is None:  # max_new/temps/keys: at submit
+                    self._ctrl_dev = (jnp.asarray(self._max_new_arr),
+                                      jnp.asarray(self._temp_arr),
+                                      jnp.asarray(self._keys))
+                if self._slot_dev is None:  # first step after a host mutation
+                    self._slot_dev = (
+                        jnp.asarray(self._last_tok),
+                        jnp.asarray(self.pos, jnp.int32),
+                        jnp.asarray(self.active),
+                        jnp.asarray(self._new_count))
         max_new_d, temps_d, keys_d = self._ctrl_dev
-        if self._slot_dev is None:  # first step after a host-side mutation
-            self._slot_dev = (
-                jnp.asarray(self._last_tok), jnp.asarray(self.pos, jnp.int32),
-                jnp.asarray(self.active), jnp.asarray(self._new_count))
         t0 = self.profiler.begin() if self.profiler is not None else 0.0
-        new_state, packed, self._slot_dev = self._step_fn(
-            self.params, self.state, *self._slot_dev,
-            max_new_d, temps_d, keys_d, eos)
+        with span("serving.step.dispatch", phases=self.phases):
+            new_state, packed, self._slot_dev = self._step_fn(
+                self.params, self.state, *self._slot_dev,
+                max_new_d, temps_d, keys_d, eos)
         self.step_dispatches += 1
         self.state = new_state
-        nxt, emit, done = np.asarray(packed)  # the one small host transfer
+        with span("serving.step.wait", phases=self.phases):
+            nxt, emit, done = np.asarray(packed)  # the one small host transfer
         if self.profiler is not None:
-            # np.asarray above already synced the step, so no fence needed
+            # np.asarray above already synced the step
             n_emit = int(emit.sum())
             dt = self.profiler.end(t0, tokens=n_emit)
             self._m_steps.inc()
             self._m_tokens.inc(n_emit)
             self._m_step_hist.observe(dt)
-        for slot in np.where(self.active)[0]:
-            rid = self.slot_req[slot]
-            r = self.results[rid]
-            tok: int | None = None
-            if emit[slot]:
-                tok = int(nxt[slot])
-                r.tokens.append(tok)
-                self._last_tok[slot] = tok
-                self.pos[slot] += 1
-                self._new_count[slot] += 1
-            if done[slot]:
-                r.finished = True
-                self.active[slot] = False
-                if self.paged:
-                    self._release_slot(slot)
-            events.append(StepEvent(rid=rid, token=tok, finished=bool(done[slot])))
+        with span("serving.step.events", phases=self.phases):
+            for slot in np.where(self.active)[0]:
+                rid = self.slot_req[slot]
+                r = self.results[rid]
+                tok: int | None = None
+                if emit[slot]:
+                    tok = int(nxt[slot])
+                    r.tokens.append(tok)
+                    self._last_tok[slot] = tok
+                    self.pos[slot] += 1
+                    self._new_count[slot] += 1
+                if done[slot]:
+                    r.finished = True
+                    self.active[slot] = False
+                    if self.paged:
+                        with span("serving.step.release", phases=self.phases,
+                                  rid=rid, slot=int(slot)):
+                            self._release_slot(slot)
+                events.append(StepEvent(rid=rid, token=tok,
+                                        finished=bool(done[slot])))
         return events
 
     def _grow_blocks(self) -> list[StepEvent]:
@@ -724,7 +788,9 @@ class ServingEngine:
                     self._m_exhausted.inc()
                 self.active[slot] = False
                 self._slot_dev = None
-                self._release_slot(slot)
+                with span("serving.step.release", phases=self.phases,
+                          rid=rid, slot=int(slot)):
+                    self._release_slot(slot)
                 events.append(StepEvent(rid=rid, token=None, finished=True))
                 continue
             self._tbl_host[slot, bi] = bid

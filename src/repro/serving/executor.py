@@ -38,6 +38,19 @@ __all__ = ["CompressedExecutor", "LCCMatvec", "GroupedLCCMatvec", "ConvLCC",
            "StepPlan", "MoEPlan", "matvecs_from_artifact"]
 
 
+def kernel_name(kernel: str, sites) -> str:
+    """The launch name of an LCC kernel over ``sites``: ``kernel`` and the
+    sites' projection names, so the device trace names each site group
+    (``_lcc_group_matmul_qkv``, ``_lcc_chain_matmul_o``,
+    ``_lcc_group_matmul_gate_up``, ``_lcc_chain_matmul_down``) and a layer's
+    index never enters it: every layer's launch of a group shares one name.
+    """
+    projs = list(dict.fromkeys(n.split(".")[1] if "." in n else n
+                               for n in sites))
+    sep = "" if all(len(p) == 1 for p in projs) else "_"
+    return f"{kernel}_{sep.join(projs)}"
+
+
 class LCCMatvec:
     """One compressed projection as a fused-kernel matvec: x [K, B] -> [N, B].
 
@@ -64,6 +77,7 @@ class LCCMatvec:
                        if cd.shared is not None else None)
         self.n_clusters = cd.shared.n_clusters if cd.shared is not None else 0
         self.interpret = interpret
+        self.kernel_name = kernel_name("_lcc_chain_matmul", [cd.name])
         # jit the whole chain (gather -> segment-sum -> fused kernel) so a
         # per-token decode loop pays one dispatch, not one per slice/stage
         self._fn = jax.jit(self._run)
@@ -76,7 +90,8 @@ class LCCMatvec:
             xk = ops.segment_sum_tpu(self.labels, xk, self.n_clusters,
                                      interpret=self.interpret)
         return ops.apply_packed_decomposition(self.packed, xk,
-                                              interpret=self.interpret)
+                                              interpret=self.interpret,
+                                              name=self.kernel_name)
 
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         vec = x.ndim == 1
@@ -118,6 +133,7 @@ class GroupedLCCMatvec:
         self.n_clusters = [cd.shared.n_clusters if cd.shared is not None else 0
                            for cd in records]
         self.interpret = interpret
+        self.kernel_name = kernel_name("_lcc_group_matmul", self.names)
         self._fn = jax.jit(self._run)
 
     def _run(self, xs):
@@ -132,7 +148,8 @@ class GroupedLCCMatvec:
                                          interpret=self.interpret)
             prep.append(xk)
         return tuple(ops.apply_packed_group(self.group, prep,
-                                            interpret=self.interpret))
+                                            interpret=self.interpret,
+                                            name=self.kernel_name))
 
     def __call__(self, xs) -> list[jnp.ndarray]:
         b = xs[0].shape[1]

@@ -25,6 +25,8 @@ import heapq
 from dataclasses import dataclass, field
 from typing import Callable
 
+from repro.obs import span
+
 from .engine import GenerationResult, ServingEngine, StepEvent
 
 __all__ = ["Request", "Scheduler"]
@@ -131,6 +133,10 @@ class Scheduler:
         blocks even by evicting cached prefixes, admission stops — strictly,
         so a big high-priority request is never starved by small ones slipping
         past it (no head-of-line bypass)."""
+        with span("serving.admit", phases=self.engine.phases):
+            return self._admit()
+
+    def _admit(self) -> list[int]:
         admitted: list[int] = []
         tr = self.tracer
         while self._heap and (~self.engine.active).any():
@@ -144,7 +150,8 @@ class Scheduler:
             was_running = bool(self.engine.active.any())
             try:
                 erid = self.engine.submit(req.prompt, max_new=req.max_new,
-                                          temperature=req.temperature)
+                                          temperature=req.temperature,
+                                          sid=req.sid)
             except Exception as e:  # isolation: one bad request never
                 self.results[req.rid] = GenerationResult(  # strands the batch
                     tokens=list(req.prompt), prompt_len=len(req.prompt),
@@ -173,6 +180,12 @@ class Scheduler:
         id namespace never surfaces here)."""
         self.admit()
         events = self.engine.step()
+        with span("serving.deliver", phases=self.engine.phases):
+            return self._deliver(events)
+
+    def _deliver(self, events) -> list[StepEvent]:
+        """Re-key the engine's events, feed the tracer and the streaming
+        callbacks, and retire what finished."""
         tr = self.tracer
         out: list[StepEvent] = []
         for ev in events:

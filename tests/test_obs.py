@@ -202,24 +202,16 @@ def test_tracer_lifecycle_deterministic(tmp_path):
 
 def test_step_profiler_ring_and_summary():
     clk = FakeClock()
-    prof = StepProfiler(capacity=4, fence_every=0, clock=clk)
+    prof = StepProfiler(capacity=4, clock=clk)
     for dt in (0.01, 0.02, 0.03, 0.04, 0.05):
         t0 = prof.begin()
         clk.tick(dt)
         prof.end(t0, tokens=10)
     assert len(prof) == 4 and prof.total_steps == 5  # ring dropped the oldest
     summ = prof.summary()
-    assert summ["steps"] == 4 and summ["fenced"] == 0
+    assert summ["steps"] == 4 and summ["total_steps"] == 5
     assert summ["tok_s"] == pytest.approx(40 / 0.14)
     assert summ["p99_ms"] == pytest.approx(50.0)
-
-
-def test_step_profiler_fences_periodically():
-    prof = StepProfiler(fence_every=2)
-    for _ in range(4):
-        t0 = prof.begin()
-        prof.end(t0, tokens=1, fence=np.zeros(1))
-    assert prof.summary()["fenced"] == 2  # every 2nd sample syncs the device
 
 
 def test_roofline_shape_from_fake_artifact():
