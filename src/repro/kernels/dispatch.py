@@ -12,7 +12,7 @@ from __future__ import annotations
 import jax
 
 __all__ = ["default_interpret", "resolve_interpret", "record_launch",
-           "launch_count", "reset_launch_count"]
+           "record_lcc_launch", "launch_count", "reset_launch_count"]
 
 
 def default_interpret() -> bool:
@@ -38,6 +38,7 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 _launch_count = 0
 _launch_metric = None  # lazily resolved obs counter (process-global registry)
+_lcc_metric = None
 
 
 def record_launch(n: int = 1) -> None:
@@ -54,6 +55,24 @@ def record_launch(n: int = 1) -> None:
             "pallas_launches_total",
             "Pallas launches recorded at trace time, process-wide")
     _launch_metric.inc(n)
+
+
+def record_lcc_launch(launch: str, body: str) -> None:
+    """Count one LCC launch named ``launch`` (its site group's name in the
+    device trace) under the ``body`` that runs it: ``row_gather`` (the
+    compiled body), ``row_gather+dense_first`` (the same, its first factor
+    as one-hot tiles on the MXU) or ``interpret`` (the interpreter's
+    gather).  Published
+    as ``lcc_launches_total{launch, body}`` in the process-global registry,
+    at trace time like :func:`record_launch`."""
+    global _lcc_metric
+    if _lcc_metric is None:
+        from repro.obs import get_global
+        _lcc_metric = get_global().counter(
+            "lcc_launches_total",
+            "LCC kernel launches recorded at trace time, by launch and body",
+            labels=("launch", "body"))
+    _lcc_metric.inc(launch=launch, body=body)
 
 
 def launch_count() -> int:

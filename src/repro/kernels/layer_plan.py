@@ -26,8 +26,8 @@ always emits the layer's full output.
 
 These kernels are gather/scatter-shaped and target the *interpreter* path
 (the environment this repo benches on); compiled Mosaic keeps the per-region
-grouped kernels, whose one-hot/MXU formulation it is built for.  The
-executor gates plan construction on ``resolve_interpret``.
+grouped kernels and their row-gather body.  The executor gates plan
+construction on ``resolve_interpret``.
 """
 from __future__ import annotations
 

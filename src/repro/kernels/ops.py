@@ -29,7 +29,7 @@ from repro.core.lcc import LCCChain, LCCDecomposition
 
 from .dispatch import record_launch
 from .group_prox import group_prox
-from .lcc_chain_matmul import lcc_chain_matmul
+from .lcc_chain_matmul import lcc_chain_matmul, pack_terms
 from .lcc_group_matmul import lcc_group_matmul
 from .lcc_matmul import lcc_factor_matmul
 from .shared_matmul import cluster_segment_sum
@@ -63,8 +63,18 @@ def _pad_dim(n: int, block: int) -> int:
     return _round_up(n, min(block, max(n, 1)))
 
 
+class _TermWords:
+    """The ``terms`` of a packed (idx, exp, sign) stream set."""
+
+    @functools.cached_property
+    def terms(self) -> np.ndarray:
+        """The streams as the compiled row-gather body reads them
+        (``lcc_chain_matmul.pack_terms``), laid out once, on the host."""
+        return pack_terms(self.idx, self.exp, self.sign)
+
+
 @dataclass(frozen=True)
-class PackedChain:
+class PackedChain(_TermWords):
     """One FP chain in the stacked kernel layout: factor axis leading."""
 
     idx: jnp.ndarray  # [P, N_pad, S] int32
@@ -83,7 +93,7 @@ class PackedChain:
 
 
 @dataclass(frozen=True)
-class PackedDecomposition:
+class PackedDecomposition(_TermWords):
     """Whole decomposition: FP slices stacked for one fused launch + dense rest."""
 
     idx: jnp.ndarray  # [E, P, N_pad, S] int32
@@ -168,7 +178,7 @@ def _pad_batch(b: int, block: int) -> tuple[int, int]:
 
 
 @dataclass(frozen=True)
-class PackedGroup:
+class PackedGroup(_TermWords):
     """G packed decompositions re-padded to common dims for ONE grouped launch.
 
     ``members`` keeps each decomposition's original packing metadata
@@ -265,21 +275,22 @@ def apply_packed_group(pg: PackedGroup, xs, *, block: int = 128,
     any_fp = any(m.col_slices for m in pg.members)
     y = None
     if any_fp:
+        rows = _round_up(pg.first_width, 8)  # all the kernel reads of a slab
         stacks = []
         for m, x in zip(pg.members, xs):
             if x.shape[0] != m.in_dim:
                 raise ValueError(f"x has {x.shape[0]} rows, member expects "
                                  f"in_dim={m.in_dim}")
             slabs = [jnp.pad(x[c0:c1].astype(jnp.float32),
-                             ((0, pg.d_pad - (c1 - c0)), (0, b_pad - b)))
+                             ((0, rows - (c1 - c0)), (0, b_pad - b)))
                      for c0, c1 in m.col_slices]
-            slabs += [jnp.zeros((pg.d_pad, b_pad), jnp.float32)
+            slabs += [jnp.zeros((rows, b_pad), jnp.float32)
                       ] * (e_max - len(slabs))
             stacks.append(jnp.stack(slabs))
         y = lcc_group_matmul(pg.idx, pg.exp, pg.sign, jnp.stack(stacks),
                              block_b=bb, first_width=pg.first_width,
-                             interpret=interpret,
-                             name=name)  # [G, N_pad, B_pad]
+                             interpret=interpret, name=name,
+                             terms=pg.terms)  # [G, N_pad, B_pad]
     outs = []
     for g, (m, x) in enumerate(zip(pg.members, xs)):
         yg = y[g, : m.out_dim, :b] if (y is not None and m.col_slices) else None
@@ -328,12 +339,14 @@ def apply_packed_chain(pc: PackedChain, x: jnp.ndarray, *, block: int = 128,
     if k != pc.in_dim:
         raise ValueError(f"x has {k} rows, chain expects in_dim={pc.in_dim}")
     bb, b_pad = _pad_batch(b, block)
+    # the fused kernel reads first_width rows; the per-factor loop carries D_pad
+    rows = _round_up(pc.first_width, 8) if fused else pc.d_pad
     x_pad = jnp.pad(x.astype(jnp.float32),
-                    ((0, pc.d_pad - k), (0, b_pad - b)))[None]
+                    ((0, rows - k), (0, b_pad - b)))[None]
     if fused:
         y = lcc_chain_matmul(pc.idx[None], pc.exp[None], pc.sign[None], x_pad,
                              block_b=bb, first_width=pc.first_width,
-                             interpret=interpret)
+                             interpret=interpret, terms=pc.terms[None])
     else:
         y = _apply_stacked_per_factor(pc.idx[None], pc.exp[None], pc.sign[None],
                                       x_pad, (pc.n_factors,), block=block,
@@ -363,14 +376,18 @@ def apply_packed_decomposition(packed: PackedDecomposition, x: jnp.ndarray, *,
     e_slices = len(packed.col_slices)
     if e_slices:
         bb, b_pad = _pad_batch(b, block)
+        # the fused kernel reads first_width rows; the per-factor loop
+        # carries D_pad
+        rows = (_round_up(packed.first_width, 8) if fused else packed.d_pad)
         x_pad = jnp.stack([
             jnp.pad(x[c0:c1].astype(jnp.float32),
-                    ((0, packed.d_pad - (c1 - c0)), (0, b_pad - b)))
+                    ((0, rows - (c1 - c0)), (0, b_pad - b)))
             for c0, c1 in packed.col_slices])
         if fused:
             y = lcc_chain_matmul(packed.idx, packed.exp, packed.sign, x_pad,
                                  block_b=bb, first_width=packed.first_width,
-                                 interpret=interpret, name=name)
+                                 interpret=interpret, name=name,
+                                 terms=packed.terms)
         else:
             y = _apply_stacked_per_factor(packed.idx, packed.exp, packed.sign,
                                           x_pad, packed.chain_lengths,

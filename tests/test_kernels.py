@@ -6,6 +6,7 @@ import pytest
 
 from repro.core.lcc import lcc_decompose
 from repro.kernels import ops, ref
+from repro.kernels.lcc_chain_matmul import pack_terms
 from repro.kernels.group_prox import group_prox
 from repro.kernels.lcc_matmul import lcc_factor_matmul
 from repro.kernels.shared_matmul import cluster_segment_sum
@@ -100,28 +101,6 @@ def test_fused_chain_padded_rows_stay_zero():
     np.testing.assert_allclose(y[: pc.out_dim], want, rtol=1e-5, atol=1e-5)
 
 
-def test_fused_kernel_onehot_formulation_matches_gather():
-    """The compiled (one-hot/MXU) decompress branch == the gather branch,
-    both run under the interpreter via the use_gather override — keeps the
-    production-TPU formulation covered by CPU CI."""
-    from repro.kernels.lcc_chain_matmul import lcc_chain_matmul
-
-    rng = np.random.default_rng(28)
-    w = rng.standard_normal((200, 16))
-    dec = lcc_decompose(w, algorithm="fp", target_snr_db=35.0)
-    packed = ops.pack_decomposition(dec)
-    bb, b_pad = 8, 8
-    x_pad = jnp.stack([
-        jnp.pad(jnp.asarray(rng.standard_normal((c1 - c0, b_pad)), jnp.float32),
-                ((0, packed.d_pad - (c1 - c0)), (0, 0)))
-        for c0, c1 in packed.col_slices])
-    args = (packed.idx, packed.exp, packed.sign, x_pad)
-    kw = dict(block_b=bb, first_width=packed.first_width, interpret=True)
-    gather = np.asarray(lcc_chain_matmul(*args, use_gather=True, **kw))
-    onehot = np.asarray(lcc_chain_matmul(*args, use_gather=False, **kw))
-    np.testing.assert_allclose(onehot, gather, rtol=1e-6, atol=1e-6)
-
-
 def test_grouped_launch_matches_per_decomposition():
     """lcc_group_matmul applies G whole decompositions in ONE launch == the
     per-decomposition fused path, across mixed shapes, chain lengths, slice
@@ -145,29 +124,148 @@ def test_grouped_launch_matches_per_decomposition():
                                    err_msg=f"group member {g}")
 
 
-def test_grouped_launch_onehot_formulation_matches_gather():
-    """The grouped kernel's compiled (one-hot/MXU) branch == its gather
-    branch under the interpreter — TPU formulation covered by CPU CI."""
+def _chain_streams(rng, g, e, p, n, w, *, out_dim=None, lengths=None,
+                   live_slices=None):
+    """Random [G, E, P, N, 2] term streams with the packers' invariants:
+    rows past ``out_dim`` carry sign 0, slice (g, e) is a chain of
+    ``lengths[g][e]`` factors continued by identity factors, and group g
+    has ``live_slices[g]`` slices (the rest all sign 0, a zero input)."""
+    idx = rng.integers(0, n, (g, e, p, n, 2))
+    idx[:, :, 0] = rng.integers(0, w, (g, e, n, 2))
+    exp = rng.integers(-3, 2, idx.shape).astype(np.int8)
+    sign = rng.choice(np.array([-1, 0, 1], np.int8), idx.shape)
+    if out_dim is not None:
+        sign[..., out_dim:, :] = 0
+    for gi, row in enumerate(lengths or []):
+        for ei, length in enumerate(row):
+            idx[gi, ei, length:, :, 0] = np.arange(n)
+            idx[gi, ei, length:, :, 1] = 0
+            sign[gi, ei, length:] = [1, 0]
+    for gi, live in enumerate(live_slices or []):
+        sign[gi, live:] = 0
+    return idx.astype(np.int32), exp, sign
+
+
+# group members as ops packs them: the FS member (24 x 40, slices of 8) has
+# no FP slice, so its slices in the launch are all sign 0 and its output is
+# its dense slices alone, which ops adds outside the launch
+OPS_MEMBERS = [((24, 16), "fp", None), ((8, 16), "fp", None),
+               ((24, 40), "fs", 8), ((12, 12), "fp", None)]
+
+
+def _member_streams(rng, members, b):
+    """``ops.pack_group`` of the ``members``' decompositions, [K, b] inputs
+    for them, and those inputs cut into slabs as ``ops.apply_packed_group``
+    cuts them."""
+    import warnings
+
+    decs = [lcc_decompose(rng.standard_normal(shape), algorithm=alg,
+                          target_snr_db=35.0, slice_width=width)
+            for shape, alg, width in members]
+    with warnings.catch_warnings():  # the badly matched group is the point
+        warnings.simplefilter("ignore")
+        pg = ops.pack_group([ops.pack_decomposition(d) for d in decs])
+    xs = [rng.standard_normal((d.shape[1], b)).astype(np.float32)
+          for d in decs]
+    rows = -(-pg.first_width // 8) * 8
+    slabs = np.zeros(pg.idx.shape[:2] + (rows, b), np.float32)
+    for gi, (m, xm) in enumerate(zip(pg.members, xs)):
+        for ei, (c0, c1) in enumerate(m.col_slices):
+            slabs[gi, ei, : c1 - c0] = xm[c0:c1]
+    return decs, pg, xs, slabs
+
+
+# (g, e, p, n_pad, first_width, x rows, batch, extra): chains are groups of
+# one; n_pad 200 is not a multiple of an SMEM tile, 256 is; a first factor
+# wider than DENSE_FIRST_MAX columns is gathered too, narrower ones are tiles
+@pytest.mark.parametrize("g,e,p,n,w,d,b,extra", [
+    (1, 3, 4, 200, 16, 200, 8,
+     dict(out_dim=190, lengths=[[4, 2, 1]])),  # identity-padded, sign-0 rows
+    (1, 2, 3, 64, 8, 8, 5, {}),  # batch not a multiple of 8, x of 8 rows
+    (1, 2, 2, 32, 8, 32, 130, {}),  # batch over one 128-lane block
+    (1, 3, 1, 96, 16, 96, 8, {}),  # a single factor
+    (1, 2, 3, 32, 40, 40, 8, {}),  # slices wider than the output
+    (1, 2, 3, 256, 520, 520, 8, {}),  # a first factor too wide for a tile
+    (3, 4, 3, 48, 12, 48, 8, dict(live_slices=[4, 2, 0])),  # zero slices
+    (2, 2, 6, 256, 13, 256, 32, dict(lengths=[[6, 3], [2, 6]])),
+    (4, 6, 9, 24, 5, 8, 5, dict(members=OPS_MEMBERS, seed=31)),  # FS member
+], ids=["chain-padded", "chain-b5", "chain-b130", "chain-p1", "chain-wide",
+        "chain-first-gather", "group-zero-slices", "group-n256",
+        "group-ops-members"])
+def test_row_gather_body_matches_gather_and_ref(g, e, p, n, w, d, b, extra):
+    """The compiled row-gather body, run under the interpreter, equals the
+    interpreter's gather body and the dense oracle, chains and groups."""
+    from repro.kernels.lcc_chain_matmul import lcc_chain_matmul
     from repro.kernels.lcc_group_matmul import lcc_group_matmul
 
-    rng = np.random.default_rng(32)
-    decs = [lcc_decompose(rng.standard_normal((16, 12)), algorithm="fp",
-                          target_snr_db=35.0) for _ in range(3)]
-    pg = ops.pack_group([ops.pack_decomposition(d) for d in decs])
-    e_max = pg.idx.shape[1]
-    stacks = []
-    for m in pg.members:
-        slabs = [jnp.pad(jnp.asarray(rng.standard_normal((c1 - c0, 4)),
-                                     jnp.float32),
-                         ((0, pg.d_pad - (c1 - c0)), (0, 0)))
-                 for c0, c1 in m.col_slices]
-        slabs += [jnp.zeros((pg.d_pad, 4), jnp.float32)] * (e_max - len(slabs))
-        stacks.append(jnp.stack(slabs))
-    args = (pg.idx, pg.exp, pg.sign, jnp.stack(stacks))
-    kw = dict(block_b=4, first_width=pg.first_width, interpret=True)
-    gather = np.asarray(lcc_group_matmul(*args, use_gather=True, **kw))
-    onehot = np.asarray(lcc_group_matmul(*args, use_gather=False, **kw))
-    np.testing.assert_allclose(onehot, gather, rtol=1e-6, atol=1e-6)
+    rng = np.random.default_rng(extra.get("seed", n + b + g))
+    bb = min(b, 128)
+    b_pad = -(-b // bb) * bb
+    if "members" in extra:
+        decs, pg, xs, x = _member_streams(rng, extra["members"], b_pad)
+        idx, exp, sign = (np.asarray(a) for a in (pg.idx, pg.exp, pg.sign))
+        assert idx.shape[:4] == (g, e, p, n) and x.shape[2] == d
+        assert pg.first_width == w
+    else:
+        idx, exp, sign = _chain_streams(rng, g, e, p, n, w, **extra)
+        x = np.zeros((g, e, d, b_pad), np.float32)
+        x[:, :, :w, :b] = rng.standard_normal((g, e, w, b))
+        x[sign.reshape(g, e, -1).any(-1) == 0] = 0.0  # a dead slice: no input
+    want = np.zeros((g, n, b_pad), np.float32)
+    for gi in range(g):
+        for ei in range(e):
+            want[gi] += np.asarray(ref.lcc_chain_apply_ref(
+                list(zip(idx[gi, ei], exp[gi, ei], sign[gi, ei])), x[gi, ei]))
+    kw = dict(block_b=bb, first_width=w, interpret=True)
+    terms = pack_terms(idx, exp, sign)
+    if g == 1:
+        def run(**k):
+            return np.asarray(lcc_chain_matmul(idx[0], exp[0], sign[0], x[0],
+                                               **kw, **k))[None]
+        terms = terms[0]
+    else:
+        def run(**k):
+            return np.asarray(lcc_group_matmul(idx, exp, sign, x, **kw, **k))
+    rows = run(use_gather=False, terms=terms)
+    gather = run(use_gather=True)
+    np.testing.assert_allclose(rows, gather, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(rows, want, rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="pass terms"):
+        run(use_gather=False)  # the row gather reads no other streams
+    if "members" in extra:
+        for gi, (dec, m, xm) in enumerate(zip(decs, pg.members, xs)):
+            if not m.col_slices:  # the FS member: nothing in the launch
+                np.testing.assert_array_equal(rows[gi], 0.0)
+            np.testing.assert_allclose(  # with its dense slices, through ops
+                np.asarray(ops.apply_packed_group(pg, xs)[gi]),
+                dec.to_dense() @ xm, rtol=1e-5, atol=1e-5)
+    if extra.get("out_dim"):
+        np.testing.assert_array_equal(rows[:, extra["out_dim"]:], 0.0)
+    for gi, live in enumerate(extra.get("live_slices", [])):
+        if live == 0:
+            np.testing.assert_array_equal(rows[gi], 0.0)
+
+
+def test_pack_terms_word_layout():
+    """A term word is the float32 bits of sign * 2^exp with the column in
+    the mantissa, term-major over rows padded to an SMEM tile; unused slots
+    are 0."""
+    from repro.kernels.lcc_chain_matmul import term_rows
+
+    idx = np.array([[[[3, 7], [0, 5]]]], np.int32)  # [E=1, P=1, N=2, S=2]
+    exp = np.array([[[[-2, 1], [0, 0]]]], np.int8)
+    sign = np.array([[[[1, -1], [0, 1]]]], np.int8)
+    words = pack_terms(idx, exp, sign)
+    rows = term_rows(2)
+    assert rows == 128
+    assert words.shape == (1, 1, 1, 2 * rows) and words.dtype == np.int32
+    live = words[0, 0, 0].reshape(2, rows)[:, :2]  # [term, row]
+    np.testing.assert_array_equal((live & -(1 << 23)).view(np.float32),
+                                  [[0.25, 0.0], [-2.0, 1.0]])
+    np.testing.assert_array_equal(live & ((1 << 23) - 1), [[3, 0], [7, 5]])
+    assert words.reshape(2, rows)[:, 2:].sum() == 0
+    with pytest.raises(ValueError):
+        pack_terms(idx, np.full_like(exp, -127), sign)
 
 
 def test_fused_kernel_interpret_override_matches():

@@ -188,23 +188,77 @@ def test_compiles_are_charged_to_the_span_open_around_them(tiny_model):
     assert _delta(_counter(get_global(), name), before) == {}
 
 
-def test_lcc_launches_are_named_by_site_group():
-    """The compiled formulation of a compressed decode step, lowered for
-    the TPU without one: one launch name per site group, each matching
-    the roofline reader's pattern."""
+LCC_LAUNCHES = {"_lcc_group_matmul_qkv", "_lcc_chain_matmul_o",
+                "_lcc_group_matmul_gate_up", "_lcc_chain_matmul_down"}
+
+
+@pytest.fixture(scope="module")
+def lcc_model():
+    """A reduced olmo-1b compressed at every site, and its decode step's
+    argument shapes for an 8-slot engine."""
     cfg = reduced_config(get_arch("olmo-1b"), vocab=128)
     params = api.init_params(jax.random.PRNGKey(0), cfg)
     art = api.compress_model(params, cfg, core.CompressionConfig(
         algorithm="fp", target_snr_db=20.0))
-    eng = ServingEngine(artifact=art, n_slots=8, max_len=64, interpret=False)
+    return cfg, art
+
+
+def _step_shapes(eng):
     z = jnp.zeros(8, jnp.int32)
     args = (eng.params, eng.state, z, z, jnp.zeros(8, bool), z, z,
             jnp.zeros(8, jnp.float32), jnp.asarray(eng._keys), np.int32(-1))
-    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(np.shape(a),
-                                                         a.dtype), args)
-    text = eng._step_fn.trace(*shapes).lower(
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype),
+                        args)
+
+
+def test_lcc_launches_are_named_by_site_group(lcc_model):
+    """The compiled formulation of a compressed decode step, lowered for
+    the TPU without one: one launch name per site group, each matching
+    the roofline reader's pattern."""
+    _, art = lcc_model
+    eng = ServingEngine(artifact=art, n_slots=8, max_len=64, interpret=False)
+    text = eng._step_fn.trace(*_step_shapes(eng)).lower(
         lowering_platforms=("tpu",)).as_text()
     names = set(re.findall(r'kernel_name = "(_lcc_[a-z_]+)"', text))
-    assert names == {"_lcc_group_matmul_qkv", "_lcc_chain_matmul_o",
-                     "_lcc_group_matmul_gate_up", "_lcc_chain_matmul_down"}
+    assert names == LCC_LAUNCHES
     assert all(re.search(r"_lcc_(chain|group)_matmul", n) for n in names)
+
+
+@pytest.mark.parametrize("path", ["decode_step", "interpreter", "wide_first"])
+def test_lcc_launches_counted_by_body(lcc_model, path):
+    """``lcc_launches_total`` counts each LCC launch a trace emits under the
+    body that runs it: tracing the compiled formulation of a compressed
+    decode step counts every site group once a layer under the row gather
+    with a one-hot first factor, as the shape rule picks for slices of at
+    most ``DENSE_FIRST_MAX`` columns; a grouped launch on this host runs
+    the interpreter's gather; a wider first factor is gathered too."""
+    from repro.kernels import ops
+    from repro.kernels.lcc_chain_matmul import (DENSE_FIRST_MAX,
+                                                lcc_chain_matmul, pack_terms)
+
+    cfg, art = lcc_model
+    before = _counter(get_global(), "lcc_launches_total")
+    if path == "decode_step":
+        eng = ServingEngine(artifact=art, n_slots=8, max_len=64,
+                            interpret=False)
+        eng._step_fn.trace(*_step_shapes(eng))
+        want = {(name, "row_gather+dense_first"): cfg.n_layers
+                for name in LCC_LAUNCHES}
+    elif path == "interpreter":
+        recs = [art.records[n] for n in sorted(art.records)
+                if n.startswith(("attn.q.", "attn.k."))][:2]
+        pg = ops.pack_group([ops.pack_decomposition(r.decomposition)
+                             for r in recs])
+        ops.apply_packed_group(pg, [jnp.ones((r.decomposition.shape[1], 4))
+                                    for r in recs], name="_lcc_probe")
+        want = {("_lcc_probe", "interpret"): 1}
+    else:  # a slice wider than the shape rule gives one-hot tiles
+        w = DENSE_FIRST_MAX + 8
+        idx = np.zeros((1, 1, 8, 2), np.int32)
+        exp = sign = np.ones((1, 1, 8, 2), np.int8)
+        lcc_chain_matmul(idx, exp, sign, jnp.ones((1, w, 8)), block_b=8,
+                         first_width=w, interpret=True, use_gather=False,
+                         name="_lcc_wide", terms=pack_terms(idx, exp, sign))
+        want = {("_lcc_wide", "row_gather"): 1}
+    new = _delta(_counter(get_global(), "lcc_launches_total"), before)
+    assert new == want
