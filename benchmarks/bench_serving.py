@@ -299,8 +299,7 @@ def main() -> None:
         cfg_moe = reduced_config(
             get_arch("mixtral-8x22b"), d_model=32, n_heads=2, n_kv_heads=2,
             head_dim=16, vocab=64, n_layers=1,
-            moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16,
-                        capacity_factor=8.0))
+            moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16))
         steps = 3 if args.steps is None else max(1, args.steps)
         warmup, prompt_len, max_len = 1, 8, 64
     else:
@@ -308,8 +307,7 @@ def main() -> None:
         cfg_moe = reduced_config(
             get_arch("mixtral-8x22b"), d_model=64, n_heads=4, n_kv_heads=4,
             head_dim=16, vocab=256, n_layers=2,
-            moe=MoESpec(n_experts=4, top_k=2, d_ff_expert=32,
-                        capacity_factor=8.0))
+            moe=MoESpec(n_experts=4, top_k=2, d_ff_expert=32))
         steps = 20 if args.steps is None else max(1, args.steps)
         warmup, prompt_len, max_len = 3, 16, 256
 

@@ -12,17 +12,20 @@ from dataclasses import dataclass, field, replace
 import jax
 import jax.numpy as jnp
 
-__all__ = ["MoESpec", "MLASpec", "SSMSpec", "ArchConfig", "ShapeCell", "SHAPE_CELLS",
+__all__ = ["MoESpec", "MLASpec", "YarnSpec", "SSMSpec", "ArchConfig", "ShapeCell", "SHAPE_CELLS",
            "input_specs", "reduced_config", "arch_to_dict", "arch_from_dict"]
 
 
 @dataclass(frozen=True)
 class MoESpec:
+    """Routed experts.  Routing is drop-free: every token reaches each of its
+    ``top_k`` experts, whatever else is in the batch.  ``norm_topk``
+    renormalises the selected gates to sum to one (Mixtral); DeepSeek-V2
+    keeps the raw softmax weights."""
     n_experts: int
     top_k: int
     d_ff_expert: int
     n_shared: int = 0
-    capacity_factor: float = 1.25
     norm_topk: bool = True
 
 
@@ -32,6 +35,19 @@ class MLASpec:
     qk_nope: int = 128
     qk_rope: int = 64
     v_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnSpec:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 configures it:
+    ``rope_scaling`` ``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow``, ``mscale``, ``mscale_all_dim``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -57,9 +73,14 @@ class ArchConfig:
     norm: str = "rms"  # rms | nonparam | ln
     pos: str = "rope"  # rope | mrope | learned | none
     rope_theta: float = 10000.0
+    yarn: YarnSpec | None = None
     attn_window: int | None = None
     tie_embeddings: bool = False
     moe: MoESpec | None = None
+    # leading layers with a dense SwiGLU of width ``d_ff`` before the MoE
+    # stack (DeepSeek-V2's ``first_k_dense_replace``); experts use
+    # ``moe.d_ff_expert``
+    n_dense_layers: int = 0
     mla: MLASpec | None = None
     ssm: SSMSpec | None = None
     hybrid_period: int = 6
@@ -175,6 +196,8 @@ def arch_from_dict(d: dict) -> ArchConfig:
         d["moe"] = MoESpec(**d["moe"])
     if d.get("mla") is not None:
         d["mla"] = MLASpec(**d["mla"])
+    if d.get("yarn") is not None:
+        d["yarn"] = YarnSpec(**d["yarn"])
     if d.get("ssm") is not None:
         d["ssm"] = SSMSpec(**d["ssm"])
     d["mrope_sections"] = tuple(d["mrope_sections"])
@@ -200,11 +223,10 @@ def reduced_config(cfg: ArchConfig, **overrides) -> ArchConfig:
         compute_dtype="float32",
     )
     if cfg.moe is not None:
-        # generous capacity => drop-free routing, so prefill/decode consistency
-        # is exact (capacity drops are inherent to GShard dispatch, not a bug)
         small["moe"] = MoESpec(n_experts=4, top_k=2, d_ff_expert=64,
                                n_shared=min(cfg.moe.n_shared, 1),
-                               capacity_factor=8.0)
+                               norm_topk=cfg.moe.norm_topk)
+        small["n_dense_layers"] = min(cfg.n_dense_layers, 1)
     if cfg.mla is not None:
         small["mla"] = MLASpec(kv_lora=32, qk_nope=32, qk_rope=16, v_dim=32)
     if cfg.ssm is not None:

@@ -12,7 +12,8 @@ from __future__ import annotations
 import jax
 
 __all__ = ["default_interpret", "resolve_interpret", "record_launch",
-           "record_lcc_launch", "launch_count", "reset_launch_count"]
+           "record_lcc_launch", "record_moe_launch", "launch_count",
+           "reset_launch_count"]
 
 
 def default_interpret() -> bool:
@@ -39,6 +40,7 @@ def resolve_interpret(interpret: bool | None) -> bool:
 _launch_count = 0
 _launch_metric = None  # lazily resolved obs counter (process-global registry)
 _lcc_metric = None
+_moe_metric = None
 
 
 def record_launch(n: int = 1) -> None:
@@ -73,6 +75,22 @@ def record_lcc_launch(launch: str, body: str) -> None:
             "LCC kernel launches recorded at trace time, by launch and body",
             labels=("launch", "body"))
     _lcc_metric.inc(launch=launch, body=body)
+
+
+def record_moe_launch(launch: str, body: str) -> None:
+    """Count one grouped expert launch named ``launch``
+    (``_moe_group_matmul_gate_up`` or ``_moe_group_matmul_down``) under the
+    ``body`` that runs it: ``compiled`` (Mosaic) or ``interpret``.  Published
+    as ``moe_launches_total{launch, body}`` in the process-global registry, at
+    trace time like :func:`record_launch`."""
+    global _moe_metric
+    if _moe_metric is None:
+        from repro.obs import get_global
+        _moe_metric = get_global().counter(
+            "moe_launches_total",
+            "grouped expert launches recorded at trace time, by launch and "
+            "body", labels=("launch", "body"))
+    _moe_metric.inc(launch=launch, body=body)
 
 
 def launch_count() -> int:

@@ -237,14 +237,13 @@ def step_plan_matmul(stages: dict[str, PackedStage], *, n_heads: int,
       kc/vc [L, B, S, Hkv, hd], kpos [L, B, S]   KV cache view
 
     ``moe`` (whole-step MoE plans): replaces the dense FFN with the full
-    routed block *in-kernel* — router logits/softmax/top-k, capacity-bounded
-    rank-and-scatter dispatch, the two expert super-stages ("eg" fused
+    routed block *in-kernel* — router logits/softmax/top-k, rank-and-scatter
+    dispatch at capacity B (drop-free), the two expert super-stages ("eg" fused
     gate+up over all experts e-major, SwiGLU, "ed" downs) and the gated
     combine — so an MoE layer costs zero extra launches.  Keys: ``router``
-    [L, d, E] f32 numpy, ``n_experts``, ``top_k``, ``capacity_factor``,
-    ``norm_topk``, ``min_capacity``, ``d_ff`` (= E * d_ff_expert).  The
-    routing math mirrors ``models.moe.moe_ffn`` exactly (the capacity is the
-    same static function of B), so plan and fallback decode agree.
+    [L, d, E] f32 numpy, ``n_experts``, ``top_k``, ``norm_topk``, ``d_ff``
+    (= E * d_ff_expert).  The routing math mirrors ``models.moe.moe_ffn``
+    exactly (no row dropped), so plan and fallback decode agree.
 
     Returns (y [d, B] f32, k_new [L, B, Hkv, hd] f32, v_new …): the final
     hidden state and the per-layer K/V rows for the caller to scatter back
@@ -262,8 +261,7 @@ def step_plan_matmul(stages: dict[str, PackedStage], *, n_heads: int,
         else ("qkv", "o", "gu", "dn")
     if moe is not None:
         n_exp, top_k = moe["n_experts"], moe["top_k"]
-        cap = int(max(moe.get("min_capacity", 4),
-                      round(b * top_k * moe["capacity_factor"] / n_exp)))
+        cap = b  # a token picks an expert at most once: nothing drops
         eff_total = moe["d_ff"]  # E * d_ff_expert
 
     inputs = [x0.astype(jnp.float32), pos.astype(jnp.int32)]
@@ -343,12 +341,10 @@ def step_plan_matmul(stages: dict[str, PackedStage], *, n_heads: int,
             ranks = (jnp.cumsum(flat_oh, axis=0) - flat_oh) \
                 .reshape(b, top_k, n_exp)
             rank = jnp.sum(ranks * sel_oh, axis=-1)  # [B, k]
-            keep = rank < cap
-            slot = sel * cap + jnp.minimum(rank, cap - 1)
-            slot = jnp.where(keep, slot, n_exp * cap)  # OOB => dropped
+            slot = sel * cap + rank
             buf = jnp.zeros((n_exp * cap, d), jnp.float32)
             for j in range(top_k):
-                buf = buf.at[slot[:, j]].add(xt, mode="drop")
+                buf = buf.at[slot[:, j]].set(xt)
             # e-major flatten for the expert super-stages: [E*d, C]
             src = buf.reshape(n_exp, cap, d).transpose(0, 2, 1) \
                 .reshape(n_exp * d, cap)
@@ -359,9 +355,8 @@ def step_plan_matmul(stages: dict[str, PackedStage], *, n_heads: int,
                 .reshape(n_exp * cap, d)
             y = jnp.zeros((b, d), jnp.float32)
             for j in range(top_k):
-                g = jnp.take(out_buf,
-                             jnp.minimum(slot[:, j], n_exp * cap - 1), axis=0)
-                y = y + (gates[:, j] * keep[:, j])[:, None] * g
+                g = jnp.take(out_buf, slot[:, j], axis=0)
+                y = y + gates[:, j][:, None] * g
             return y.T
 
         stage_vals = {name: _load_refs(stage_refs[name])

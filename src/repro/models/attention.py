@@ -21,8 +21,9 @@ import jax.numpy as jnp
 
 from repro.distributed.act_shard import constrain
 
-from .layers import (apply_mrope, apply_rope, dense_init, linear, site_fmt,
-                     site_linear, site_linear_group)
+from .layers import (apply_mrope, apply_rope, apply_rope_pairs, dense_init,
+                     linear, rms_norm, rope_inv_freq, site_fmt, site_linear,
+                     site_linear_group, yarn_mscale)
 
 __all__ = [
     "AttnParams",
@@ -39,6 +40,8 @@ __all__ = [
     "mla_extend",
     "MLACache",
     "PagedMLACache",
+    "MLARope",
+    "mla_rope",
 ]
 
 _NEG = -1e30
@@ -117,9 +120,11 @@ def _project_qkv(p, x, n_heads, n_kv, head_dim):
     return q, k, v
 
 
-def _sdpa(q, k, v, mask):
-    """q [B,Sq,Hkv,G,D], k/v [B,Sk,Hkv,D], additive mask [B,1,1,Sq,Sk] or None."""
-    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
+def _sdpa(q, k, v, mask, scale=None):
+    """q [B,Sq,Hkv,G,D], k/v [B,Sk,Hkv,D], additive mask [B,1,1,Sq,Sk] or None;
+    ``scale`` on the scores defaults to 1/sqrt(D)."""
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     if mask is not None:
         scores = scores + mask
@@ -324,12 +329,38 @@ class MLACache(NamedTuple):
     kpos: jnp.ndarray  # [B, Smax]
 
 
+class MLARope(NamedTuple):
+    """Static rope of the MLA rotary branch: inverse frequencies [Dr/2], the
+    factor on cos/sin, and the softmax scale of the scores."""
+    inv_freq: tuple
+    cos_scale: float
+    softmax_scale: float
+
+
+def mla_rope(qk_nope: int, qk_rope: int, rope_theta: float, yarn=None
+             ) -> MLARope:
+    """DeepSeek-V2's MLA rope: YaRN frequencies over the ``qk_rope`` dims,
+    cos/sin times mscale(mscale) / mscale(mscale_all_dim), and the softmax
+    scale (qk_nope + qk_rope)^-1/2 times mscale(mscale_all_dim)^2 (1.5896 at
+    factor 40, mscale_all_dim 0.707)."""
+    inv = tuple(float(f) for f in rope_inv_freq(qk_rope, rope_theta, yarn))
+    scale = (qk_nope + qk_rope) ** -0.5
+    cos_scale = 1.0
+    if yarn is not None:
+        m_all = yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        cos_scale = yarn_mscale(yarn.factor, yarn.mscale) / m_all
+        scale *= m_all * m_all
+    return MLARope(inv, cos_scale, scale)
+
+
 def init_mla(key, d_model: int, n_heads: int, *, kv_lora: int, qk_nope: int,
              qk_rope: int, v_dim: int, dtype):
     ks = jax.random.split(key, 6)
     return {
         "q": dense_init(ks[0], d_model, n_heads * (qk_nope + qk_rope), dtype),
         "dkv": dense_init(ks[1], d_model, kv_lora, dtype),
+        # kv_a_layernorm: RMS norm of the latent before it is cached/expanded
+        "kv_norm": jnp.ones((kv_lora,), dtype),
         "kr": dense_init(ks[2], d_model, qk_rope, dtype),
         "uk": dense_init(ks[3], kv_lora, n_heads * qk_nope, dtype),
         "uv": dense_init(ks[4], kv_lora, n_heads * v_dim, dtype),
@@ -338,14 +369,16 @@ def init_mla(key, d_model: int, n_heads: int, *, kv_lora: int, qk_nope: int,
 
 
 def _mla_qkv(p, x, c_kv, k_rope_src, positions, kpositions, n_heads, qk_nope, qk_rope, v_dim,
-             rope_theta, executor=None, site=None):
+             rope: MLARope, executor=None, site=None):
+    """``c_kv`` is the normed latent (as cached); ``k_rope_src`` the rotary
+    key branch before rope (as cached)."""
     b, s, _ = x.shape
     sk = c_kv.shape[1]
     sn = site_fmt(site)
     q = site_linear(executor, sn("q"), p["q"], x).reshape(
         b, s, n_heads, qk_nope + qk_rope)
     q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
-    q_rope = apply_rope(q_rope, positions, rope_theta)
+    q_rope = apply_rope_pairs(q_rope, positions, rope.inv_freq, rope.cos_scale)
     # uk/uv share the latent-cache input: one grouped launch when compressed
     uk, uv = site_linear_group(executor, (sn("uk"), sn("uv")),
                                (p["uk"], p["uv"]), c_kv)
@@ -353,20 +386,21 @@ def _mla_qkv(p, x, c_kv, k_rope_src, positions, kpositions, n_heads, qk_nope, qk
                        "batch", None, "model", None)
     v = constrain(uv.reshape(b, sk, n_heads, v_dim),
                   "batch", None, "model", None)
-    k_rope = apply_rope(k_rope_src[:, :, None, :], kpositions, rope_theta)  # [B,Sk,1,Dr]
+    k_rope = apply_rope_pairs(k_rope_src[:, :, None, :], kpositions,
+                              rope.inv_freq, rope.cos_scale)  # [B,Sk,1,Dr]
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
     k_full = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, sk, n_heads, qk_rope))], axis=-1)
     return q_full, k_full, v
 
 
 def mla_prefill(p, x, positions, *, n_heads, kv_lora, qk_nope, qk_rope, v_dim,
-                rope_theta=10000.0, q_chunk: int = 1024, unroll_chunks: bool = False,
+                rope: MLARope, q_chunk: int = 1024, unroll_chunks: bool = False,
                 causal_skip: bool = False):
     b, s, _ = x.shape
-    c_kv = linear(p["dkv"], x)  # [B,S,dc]
+    c_kv = rms_norm(linear(p["dkv"], x), p["kv_norm"])  # [B,S,dc]
     k_rope_src = linear(p["kr"], x)  # [B,S,Dr]
     q, k, v = _mla_qkv(p, x, c_kv, k_rope_src, positions, positions, n_heads,
-                       qk_nope, qk_rope, v_dim, rope_theta)
+                       qk_nope, qk_rope, v_dim, rope)
     # MLA heads are full multi-head (n_kv == n_heads): reuse the GQA kernel path
     qg = q.reshape(b, s, n_heads, 1, qk_nope + qk_rope)
     kpos = jnp.arange(s)
@@ -374,7 +408,7 @@ def mla_prefill(p, x, positions, *, n_heads, kv_lora, qk_nope, qk_rope, v_dim,
     def chunk_out(q_c, qpos_c, k_c, v_c, kpos_c):
         m = (kpos_c[None, :] <= qpos_c[:, None])
         mask = jnp.where(m, 0.0, _NEG)[None, None, None]
-        return _sdpa(q_c, k_c, v_c, mask)
+        return _sdpa(q_c, k_c, v_c, mask, rope.softmax_scale)
 
     n_chunks = max(1, s // q_chunk) if s % q_chunk == 0 else 1
     if n_chunks == 1:
@@ -404,7 +438,7 @@ def mla_prefill(p, x, positions, *, n_heads, kv_lora, qk_nope, qk_rope, v_dim,
 
 
 def mla_decode(p, x, cache: MLACache, pos, *, n_heads, kv_lora, qk_nope, qk_rope,
-               v_dim, rope_theta=10000.0, executor=None, site: str | None = None):
+               v_dim, rope: MLARope, executor=None, site: str | None = None):
     """``cache`` may be a :class:`PagedMLACache` — the latent/rope pools are
     gathered through the block tables into the contiguous logical view and the
     new latent is scattered back into its pool block (cf. attention_decode)."""
@@ -416,6 +450,7 @@ def mla_decode(p, x, cache: MLACache, pos, *, n_heads, kv_lora, qk_nope, qk_rope
     smax = c_cur.shape[1]
     c_new, kr_new = site_linear_group(executor, (sn("dkv"), sn("kr")),
                                       (p["dkv"], p["kr"]), x)  # [B,1,dc/Dr]
+    c_new = rms_norm(c_new, p["kv_norm"])
     onehot = jax.nn.one_hot(pos, smax, dtype=c_cur.dtype)
     c_kv = c_cur * (1 - onehot)[..., None] + onehot[..., None] * c_new
     k_rope = kr_cur * (1 - onehot)[..., None] + onehot[..., None] * kr_new
@@ -430,33 +465,33 @@ def mla_decode(p, x, cache: MLACache, pos, *, n_heads, kv_lora, qk_nope, qk_rope
 
     kpositions = jnp.maximum(kpos, 0)
     q, k, v = _mla_qkv(p, x, c_kv, k_rope, pos[:, None], kpositions, n_heads,
-                       qk_nope, qk_rope, v_dim, rope_theta,
+                       qk_nope, qk_rope, v_dim, rope,
                        executor=executor, site=site)
     qg = q.reshape(b, 1, n_heads, 1, qk_nope + qk_rope)
     valid = (kpos >= 0) & (kpos <= pos[:, None])
     mask = jnp.where(valid, 0.0, _NEG)[:, None, None, None, :]
-    out = _sdpa(qg, k, v, mask)
+    out = _sdpa(qg, k, v, mask, rope.softmax_scale)
     out = out.reshape(b, 1, n_heads * v_dim)
     return site_linear(executor, sn("o"), p["o"], out.astype(x.dtype)), new_cache
 
 
 def mla_extend(p, x, positions, past_c, past_kr, past_kpos, *, n_heads,
-               qk_nope, qk_rope, v_dim, rope_theta=10000.0):
+               qk_nope, qk_rope, v_dim, rope: MLARope):
     """MLA prefill continuation against a resident latent prefix.
 
     ``past_c`` [B,C,dc] / ``past_kr`` [B,C,Dr] are the gathered compressed-KV
     prefix (pool layout: pre-rope rotary branch, latent as stored), masked by
     ``past_kpos`` [B,C].  Returns ``(out, c_tail, kr_tail)``."""
     b, t, _ = x.shape
-    c_t = linear(p["dkv"], x)  # [B,T,dc]
+    c_t = rms_norm(linear(p["dkv"], x), p["kv_norm"])  # [B,T,dc]
     kr_t = linear(p["kr"], x)  # [B,T,Dr]
     c_all = jnp.concatenate([past_c, c_t], axis=1)
     kr_all = jnp.concatenate([past_kr, kr_t], axis=1)
     kpos = jnp.concatenate([past_kpos, positions], axis=1)  # [B, C+T]
     q, k, v = _mla_qkv(p, x, c_all, kr_all, positions, jnp.maximum(kpos, 0),
-                       n_heads, qk_nope, qk_rope, v_dim, rope_theta)
+                       n_heads, qk_nope, qk_rope, v_dim, rope)
     valid = (kpos[:, None, :] >= 0) & (kpos[:, None, :] <= positions[:, :, None])
     mask = jnp.where(valid, 0.0, _NEG)[:, None, None]  # [B,1,1,T,C+T]
     qg = q.reshape(b, t, n_heads, 1, qk_nope + qk_rope)
-    out = _sdpa(qg, k, v, mask).reshape(b, t, n_heads * v_dim)
+    out = _sdpa(qg, k, v, mask, rope.softmax_scale).reshape(b, t, n_heads * v_dim)
     return linear(p["o"], out.astype(x.dtype)), c_t, kr_t

@@ -187,19 +187,30 @@ def _dense_sites(params, cfg) -> list[DenseSite]:
 
 
 def _moe_sites(params, cfg) -> list[DenseSite]:
+    """Leading dense blocks (``dense_blocks``, layers 0..nd-1) as dense
+    layers; MoE block j is layer nd + j in every site name."""
+    nd = cfg.n_dense_layers
     sites: list[DenseSite] = []
+    for li in range(nd):
+        sites += _ffn_sites((li,), base=("dense_blocks", "ffn"))
+        sites += _attn_sites(cfg, ("dense_blocks", "attn"), (li,), "attn")
     ffn = params["blocks"]["ffn"]
-    for li in range(cfg.n_layers):
+    for li in range(nd, cfg.n_layers):
+        j = li - nd
         for p in ("gate", "up", "down"):
             for e in range(cfg.moe.n_experts):
                 # expert stacks are raw [L, E, in, out] arrays (no "w" level)
                 sites.append(DenseSite(name=f"moe.{p}.l{li}.e{e}",
                                        path=("blocks", "ffn", p),
-                                       index=(li, e)))
+                                       index=(j, e)))
         if "shared" in ffn:
-            sites += _ffn_sites((li,), tag="moe.shared",
-                                base=("blocks", "ffn", "shared"))
-        sites += _attn_sites(cfg, ("blocks", "attn"), (li,), "attn")
+            sites += [DenseSite(name=f"moe.shared.{p}.l{li}",
+                                path=("blocks", "ffn", "shared", p, "w"),
+                                index=(j,))
+                      for p in ("gate", "up", "down")]
+        sites += [DenseSite(name=f"attn.{s.name.split('.')[1]}.l{li}",
+                            path=s.path, index=s.index)
+                  for s in _attn_sites(cfg, ("blocks", "attn"), (j,), "attn")]
     return sites
 
 

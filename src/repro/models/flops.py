@@ -59,9 +59,17 @@ def _layer_params(cfg: ArchConfig, active_only: bool) -> int:
     return _attn_params(cfg) + _ffn_params(cfg, active_only)
 
 
+def _stack_params(cfg: ArchConfig, active_only: bool) -> int:
+    """All layers: leading dense blocks (dense SwiGLU of ``d_ff``), then
+    the main stack."""
+    nd = cfg.n_dense_layers
+    return (nd * (_attn_params(cfg) + 3 * cfg.d_model * cfg.d_ff)
+            + (cfg.n_layers - nd) * _layer_params(cfg, active_only))
+
+
 def param_count(cfg: ArchConfig, active_only: bool = False) -> int:
     """Non-embedding parameters (embedding included separately below)."""
-    body = cfg.n_layers * _layer_params(cfg, active_only)
+    body = _stack_params(cfg, active_only)
     if cfg.family == "hybrid":
         n_shared_blocks = 1  # weights shared across insertions
         body += n_shared_blocks * (_attn_params(cfg) + 3 * cfg.d_model * cfg.d_ff)
@@ -88,7 +96,7 @@ def model_flops(cfg: ArchConfig, cell: ShapeCell) -> float:
     if cfg.family == "hybrid":
         body = _hybrid_active_body(cfg)
     else:
-        body = cfg.n_layers * _layer_params(cfg, active_only=True)
+        body = _stack_params(cfg, active_only=True)
         if cfg.enc_layers:
             body += cfg.enc_layers * (_attn_params(cfg) + 2 * cfg.d_model * cfg.d_ff)
             body += cfg.n_layers * _attn_params(cfg)

@@ -24,6 +24,9 @@ __all__ = [
     "non_parametric_ln",
     "apply_rope",
     "apply_mrope",
+    "yarn_mscale",
+    "rope_inv_freq",
+    "apply_rope_pairs",
     "swiglu",
     "gelu_mlp",
 ]
@@ -143,6 +146,59 @@ def apply_rope(x, positions, theta: float = 10000.0):
     sin = sin[:, :, None, :]
     cos = cos[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, ``0.1 * mscale * ln(factor) + 1``
+    (1 for no scaling)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def rope_inv_freq(dim: int, theta: float, yarn=None) -> np.ndarray:
+    """Inverse frequencies [dim/2] (float64) of a rotary branch of ``dim``
+    channels: ``theta^(-2i/dim)``, blended by YaRN (``yarn``: a
+    ``configs.base.YarnSpec``) between the original frequency and it divided
+    by ``factor``.  Bands that turn more than ``beta_fast`` times over the
+    original context keep their frequency, bands that turn fewer than
+    ``beta_slow`` times are interpolated, with a linear ramp between (DeepSeek-
+    V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    half = dim // 2
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if yarn is None:
+        return extra
+
+    def corr_dim(rotations):
+        return (dim * np.log(yarn.original_max_position
+                             / (rotations * 2 * np.pi))) / (2 * np.log(theta))
+
+    low = max(int(np.floor(corr_dim(yarn.beta_fast))), 0)
+    high = min(int(np.ceil(corr_dim(yarn.beta_slow))), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    keep = 1.0 - ramp  # 1: original frequency, 0: interpolated
+    return extra / yarn.factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope_pairs(x, positions, inv_freq, scale: float = 1.0):
+    """Rotary embedding in DeepSeek-V2's layout: x [B, S, H, D] is read as
+    interleaved pairs (2i, 2i+1), permuted to halves (i, i + D/2) and rotated
+    by ``positions`` [B, S] x ``inv_freq`` [D/2]; ``scale`` multiplies cos and
+    sin (YaRN's mscale ratio).  The output stays in the halves layout: rotated
+    queries and keys only meet in dot products, which the permutation leaves
+    unchanged."""
+    b, s, h, d = x.shape
+    xf = x.astype(jnp.float32).reshape(b, s, h, d // 2, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(
+        inv_freq, jnp.float32)  # [B, S, D/2]
+    cos = (jnp.cos(ang) * scale)[:, :, None, :]
+    sin = (jnp.sin(ang) * scale)[:, :, None, :]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 

@@ -2,7 +2,11 @@
 
 Block layout per family:
   dense / vlm:   x += attn(norm(x));   x += swiglu(norm(x))
-  moe:           x += attn|mla(norm(x)); x += moe(norm(x)) [+ shared experts]
+  moe:           x += attn|mla(norm(x)); x += moe(norm(x)) [+ shared experts];
+                 the first ``n_dense_layers`` blocks (``params["dense_blocks"]``,
+                 DeepSeek-V2's ``first_k_dense_replace``) have a dense SwiGLU of
+                 width ``d_ff`` instead and run before the MoE stack.  Their
+                 decode-cache rows are the first rows of the same state leaves.
   ssm (rwkv6):   x += timemix(norm(x)); x += channelmix(norm(x))
   hybrid(zamba): groups of ``hybrid_period`` mamba2 blocks, one *weight-shared*
                  attention+MLP block between groups (the zamba2 trick: depth
@@ -38,11 +42,12 @@ from .attention import (
     mla_decode,
     mla_extend,
     mla_prefill,
+    mla_rope,
 )
 from .layers import (dense_init, linear, non_parametric_ln, rms_norm,
                      site_linear, site_linear_group, swiglu)
 from .mamba2 import Mamba2State, init_mamba2, mamba2_decode, mamba2_prefill
-from .moe import init_moe, moe_ffn, moe_ffn_manual
+from .moe import ExpertStack, init_moe, moe_ffn, moe_ffn_manual
 from .rwkv6 import (
     RWKV6State,
     init_rwkv6,
@@ -90,7 +95,8 @@ def _norm_param(cfg: ArchConfig, d):
 # ---------------------------------------------------------------------------
 
 
-def _init_block(key, cfg: ArchConfig):
+def _init_block(key, cfg: ArchConfig, dense_ffn: bool = False):
+    """One block; ``dense_ffn`` gives an MoE config's leading dense block."""
     ks = jax.random.split(key, 4)
     p: dict[str, Any] = {"ln1": _norm_param(cfg, cfg.d_model),
                          "ln2": _norm_param(cfg, cfg.d_model)}
@@ -111,7 +117,7 @@ def _init_block(key, cfg: ArchConfig):
     else:
         p["attn"] = init_attention(ks[0], cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                                    cfg.hd, cfg.pdtype, qkv_bias=cfg.qkv_bias)
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense_ffn:
         p["ffn"] = init_moe(ks[1], cfg.d_model, cfg.moe.d_ff_expert, cfg.moe.n_experts,
                             cfg.moe.n_shared, cfg.pdtype)
     else:
@@ -147,12 +153,76 @@ def init_params(key, cfg: ArchConfig):
         "final_ln": _norm_param(cfg, cfg.d_model),
     }
     block_keys = jax.random.split(k_blocks, cfg.n_layers)
-    params["blocks"] = jax.vmap(lambda k: _init_block(k, cfg))(block_keys)
+    nd = cfg.n_dense_layers
+    if nd:
+        params["dense_blocks"] = jax.vmap(
+            lambda k: _init_block(k, cfg, dense_ffn=True))(block_keys[:nd])
+    params["blocks"] = jax.vmap(lambda k: _init_block(k, cfg))(block_keys[nd:])
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(k_head, cfg.d_model, cfg.vocab, cfg.pdtype)
     if cfg.family == "hybrid":
         params["shared_attn"] = _init_shared_attn(k_shared, cfg)
     return params
+
+
+def _layer_groups(params, cfg: ArchConfig):
+    """The stacked layer groups in order: ``(blocks, first layer, layers,
+    dense_ffn)`` — the leading dense blocks, then the main stack."""
+    nd = cfg.n_dense_layers
+    groups = [(params["dense_blocks"], 0, nd, True)] if nd else []
+    if cfg.n_layers > nd:
+        groups.append((params["blocks"], nd, cfg.n_layers - nd, False))
+    return groups
+
+
+def _rows(a, lo: int, n: int, total: int):
+    """Layer rows ``[lo, lo + n)`` of a stacked leaf (the leaf itself when
+    that is all of it)."""
+    return a if (lo, n) == (0, total) else a[lo:lo + n]
+
+
+def _concat_layers(parts):
+    """Per-group stacked outputs joined along the layer axis."""
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
+
+
+_EXPERTS = ("gate", "up", "down")
+
+
+def _split_experts(gblocks, dense_ffn: bool):
+    """(the blocks without their routed-expert stacks, the stacks) of an MoE
+    group, else (blocks, None).  A layer scan then hands the grouped launches
+    the whole stacks and the layer index (``moe.ExpertStack``) instead of
+    slicing — and so copying — every expert of each layer."""
+    ffn = gblocks.get("ffn", {})
+    if dense_ffn or "router" not in ffn:
+        return gblocks, None
+    rest = {k: v for k, v in ffn.items() if k not in _EXPERTS}
+    return {**gblocks, "ffn": rest}, {k: ffn[k] for k in _EXPERTS}
+
+
+def _with_experts(bp, layer, stacks):
+    """Block ``bp`` of a scan with its experts read from ``stacks`` at
+    ``layer``."""
+    views = {k: ExpertStack(v, layer) for k, v in stacks.items()}
+    return {**bp, "ffn": {**bp["ffn"], **views}}
+
+
+def _mla_rope(cfg: ArchConfig):
+    return mla_rope(cfg.mla.qk_nope, cfg.mla.qk_rope, cfg.rope_theta, cfg.yarn)
+
+
+def _moe_or_swiglu(cfg: ArchConfig, p, x, dense_ffn: bool, **kw):
+    """The block's FFN: routed experts, or the dense SwiGLU of a dense block
+    (or of a config without experts)."""
+    if cfg.moe is None or dense_ffn:
+        return swiglu(p, x)
+    moe_fn = moe_ffn_manual if cfg.moe_manual else moe_ffn
+    y, _aux = moe_fn(p, x, n_experts=cfg.moe.n_experts, top_k=cfg.moe.top_k,
+                     norm_topk=cfg.moe.norm_topk, **kw)
+    return y
 
 
 # ---------------------------------------------------------------------------
@@ -161,7 +231,7 @@ def init_params(key, cfg: ArchConfig):
 
 
 def _block_fwd(cfg: ArchConfig, p, x, positions, *, unroll: bool, state=None,
-               mrope_positions=None):
+               mrope_positions=None, dense_ffn: bool = False):
     """One block forward. Returns (x, per-layer state-out or aux)."""
     if cfg.family == "ssm":
         tm_in = _norm(cfg, p["ln1"], x)
@@ -185,7 +255,7 @@ def _block_fwd(cfg: ArchConfig, p, x, positions, *, unroll: bool, state=None,
         y, c_kv, k_rope = mla_prefill(p["attn"], attn_in, positions, n_heads=cfg.n_heads,
                                       kv_lora=cfg.mla.kv_lora, qk_nope=cfg.mla.qk_nope,
                                       qk_rope=cfg.mla.qk_rope, v_dim=cfg.mla.v_dim,
-                                      rope_theta=cfg.rope_theta, q_chunk=cfg.q_chunk,
+                                      rope=_mla_rope(cfg), q_chunk=cfg.q_chunk,
                                       unroll_chunks=unroll,
                                       causal_skip=cfg.causal_chunk_skip)
         kv = (c_kv, k_rope)
@@ -199,14 +269,7 @@ def _block_fwd(cfg: ArchConfig, p, x, positions, *, unroll: bool, state=None,
             causal_skip=cfg.causal_chunk_skip)
         kv = (k, v)
     x = x + y
-    ffn_in = _norm(cfg, p["ln2"], x)
-    if cfg.moe is not None:
-        moe_fn = moe_ffn_manual if cfg.moe_manual else moe_ffn
-        y, _aux = moe_fn(p["ffn"], ffn_in, n_experts=cfg.moe.n_experts,
-                         top_k=cfg.moe.top_k, capacity_factor=cfg.moe.capacity_factor,
-                         norm_topk=cfg.moe.norm_topk)
-    else:
-        y = swiglu(p["ffn"], ffn_in)
+    y = _moe_or_swiglu(cfg, p["ffn"], _norm(cfg, p["ln2"], x), dense_ffn)
     return x + y, kv, None
 
 
@@ -238,9 +301,9 @@ def forward(params, cfg: ArchConfig, *, tokens=None, embeds=None, positions=None
 
     blocks = params["blocks"]
 
-    def one(x, bp):
+    def one(x, bp, dense_ffn=False):
         return _block_fwd(cfg, bp, x, positions, unroll=unroll,
-                          mrope_positions=positions3)
+                          mrope_positions=positions3, dense_ffn=dense_ffn)
 
     if cfg.family == "hybrid":
         period = cfg.hybrid_period
@@ -289,27 +352,40 @@ def forward(params, cfg: ArchConfig, *, tokens=None, embeds=None, positions=None
             cache_out = None
     elif unroll:
         cache_list = []
-        if collect_cache:
-            for li in range(cfg.n_layers):
-                bp = jax.tree.map(lambda a: a[li], blocks)
-                x, c, _extra = one(x, bp)
-                cache_list.append(c)
-        else:
-            xonly = lambda x, bp: one(x, bp)[0]  # noqa: E731
-            fn = jax.checkpoint(xonly) if cfg.remat else xonly
-            for li in range(cfg.n_layers):
-                bp = jax.tree.map(lambda a: a[li], blocks)
-                x = fn(x, bp)
+        for gblocks, _lo, n, dense in _layer_groups(params, cfg):
+            if collect_cache:
+                for li in range(n):
+                    bp = jax.tree.map(lambda a: a[li], gblocks)
+                    x, c, _extra = one(x, bp, dense)
+                    cache_list.append(c)
+            else:
+                xonly = lambda x, bp, d=dense: one(x, bp, d)[0]  # noqa: E731
+                fn = jax.checkpoint(xonly) if cfg.remat else xonly
+                for li in range(n):
+                    bp = jax.tree.map(lambda a: a[li], gblocks)
+                    x = fn(x, bp)
         cache_out = cache_list if collect_cache else None
     else:
-        def body(x, bp):
-            x, c, _extra = one(x, bp)
-            return x, c if collect_cache else None
+        parts = []
+        for gblocks, _lo, n, dense in _layer_groups(params, cfg):
+            # serving prefill reads the expert stacks in place; training
+            # slices them (the gradient of a whole stack per layer is not
+            # worth the copy it saves)
+            gblocks, stacks = (_split_experts(gblocks, dense) if collect_cache
+                               else (gblocks, None))
 
-        if cfg.remat:
-            body = jax.checkpoint(body)
-        x, stacked = jax.lax.scan(body, x, blocks)
-        cache_out = stacked if collect_cache else None
+            def body(x, xs, dense=dense, stacks=stacks):
+                bp = xs if stacks is None else _with_experts(*xs, stacks)
+                x, c, _extra = one(x, bp, dense)
+                return x, c if collect_cache else None
+
+            if cfg.remat:
+                body = jax.checkpoint(body)
+            x, stacked = jax.lax.scan(
+                body, x, gblocks if stacks is None else (gblocks,
+                                                         jnp.arange(n)))
+            parts.append(stacked)
+        cache_out = _concat_layers(parts) if collect_cache else None
 
     x = _norm(cfg, params["final_ln"], x)
     return x, cache_out
@@ -612,12 +688,15 @@ def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = Fa
                "attn_kpos": akp2}
     elif cfg.mla is not None:
         tbl = state.get("block_tbl")  # paged: closure constant across layers
+        rope = _mla_rope(cfg)
 
-        def body_for(li):
+        def body_for(li, dense_ffn, stacks=None):
             ex = executor if li is not None else None
 
             def body(x, xs):
-                bp, ck, kr, kp = xs
+                bp, ck, kr, kp = xs[:4]
+                if stacks is not None:
+                    bp = _with_experts(bp, xs[4], stacks)
                 cache = (PagedMLACache(c_kv=ck, k_rope=kr, kpos=kp, tbl=tbl)
                          if tbl is not None
                          else MLACache(c_kv=ck, k_rope=kr, kpos=kp))
@@ -625,35 +704,44 @@ def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = Fa
                     bp["attn"], _norm(cfg, bp["ln1"], x), cache, pos,
                     n_heads=cfg.n_heads, kv_lora=cfg.mla.kv_lora,
                     qk_nope=cfg.mla.qk_nope, qk_rope=cfg.mla.qk_rope,
-                    v_dim=cfg.mla.v_dim, rope_theta=cfg.rope_theta,
+                    v_dim=cfg.mla.v_dim, rope=rope,
                     executor=ex,
                     site=f"attn.{{}}.l{li}" if ex is not None else None)
                 x = x + y
                 ffn_in = _norm(cfg, bp["ln2"], x)
-                if cfg.moe is not None:
-                    moe_fn = moe_ffn_manual if cfg.moe_manual else moe_ffn
-                    kw = ({"executor": ex, "site_tag": f"l{li}"}
-                          if ex is not None and not cfg.moe_manual else {})
-                    y, _ = moe_fn(bp["ffn"], ffn_in, n_experts=cfg.moe.n_experts,
-                                  top_k=cfg.moe.top_k,
-                                  capacity_factor=cfg.moe.capacity_factor,
-                                  norm_topk=cfg.moe.norm_topk, **kw)
-                elif ex is not None:
+                if ex is not None and (cfg.moe is None or dense_ffn):
                     y = _sites_swiglu(ex, f"ffn.{{}}.l{li}")(bp["ffn"], ffn_in)
                 else:
-                    y = swiglu(bp["ffn"], ffn_in)
+                    kw = ({"executor": ex, "site_tag": f"l{li}"}
+                          if ex is not None and not cfg.moe_manual else {})
+                    y = _moe_or_swiglu(cfg, bp["ffn"], ffn_in, dense_ffn, **kw)
                 return x + y, (c2.c_kv, c2.k_rope, c2.kpos)
             return body
 
-        xs_all = (blocks, state["c_kv"], state["k_rope"], state["kpos"])
-        if executor is None:
-            x, outs = _scan(body_for(None), x, xs_all, unroll)
-        else:
-            x, outs = _unrolled_layers(body_for, x, xs_all, cfg.n_layers)
+        parts = []
+        for gblocks, lo, n, dense in _layer_groups(params, cfg):
+            xs_all = (gblocks,) + tuple(
+                _rows(state[k], lo, n, cfg.n_layers)
+                for k in ("c_kv", "k_rope", "kpos"))
+            if executor is None:
+                gb, stacks = _split_experts(gblocks, dense)
+                xs = (gb,) + xs_all[1:]
+                if stacks is not None:
+                    xs += (jnp.arange(n),)
+                x, outs = _scan(body_for(None, dense, stacks), x, xs, unroll)
+            else:
+                x, outs = _unrolled_layers(
+                    lambda li, lo=lo, d=dense: body_for(lo + li, d), x,
+                    xs_all, n)
+            parts.append(outs)
+        outs = _concat_layers(parts)
         new = {"c_kv": outs[0], "k_rope": outs[1], "kpos": outs[2]}
         if tbl is not None:
             new["block_tbl"] = tbl
     else:
+        if cfg.n_dense_layers:
+            raise NotImplementedError(
+                "leading dense layers are served with MLA attention only")
         tbl = state.get("block_tbl")
         # whole-step layer plan: when the executor can express the full layer
         # stack as one stacked-grid launch, the per-layer loop (and all its
@@ -669,14 +757,9 @@ def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = Fa
 
                 def ffn_fn(p, ffn_in):
                     if cfg.moe is not None:
-                        moe_fn = moe_ffn_manual if cfg.moe_manual else moe_ffn
                         kw = ({"executor": ex, "site_tag": f"l{li}"}
                               if ex is not None and not cfg.moe_manual else {})
-                        y, _ = moe_fn(p, ffn_in, n_experts=cfg.moe.n_experts,
-                                      top_k=cfg.moe.top_k,
-                                      capacity_factor=cfg.moe.capacity_factor,
-                                      norm_topk=cfg.moe.norm_topk, **kw)
-                        return y
+                        return _moe_or_swiglu(cfg, p, ffn_in, False, **kw)
                     if ex is not None:
                         return _sites_swiglu(ex, f"ffn.{{}}.l{li}")(p, ffn_in)
                     return swiglu(p, ffn_in)
@@ -733,47 +816,42 @@ def forward_extend(params, cfg: ArchConfig, tokens, positions, past, last, *,
     """
     b, t = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.cdtype)
-    blocks = params["blocks"]
+    names = ("c_kv", "k_rope") if cfg.mla is not None else ("k", "v")
 
-    def ffn_fn(bp, ffn_in):
-        if cfg.moe is not None:
-            moe_fn = moe_ffn_manual if cfg.moe_manual else moe_ffn
-            y, _ = moe_fn(bp["ffn"], ffn_in, n_experts=cfg.moe.n_experts,
-                          top_k=cfg.moe.top_k,
-                          capacity_factor=cfg.moe.capacity_factor,
-                          norm_topk=cfg.moe.norm_topk)
-            return y
-        return swiglu(bp["ffn"], ffn_in)
-
-    if cfg.mla is not None:
+    def body_for(dense_ffn, stacks):
         def body(x, xs):
-            bp, pc, pkr, pkp = xs
-            y, c_t, kr_t = mla_extend(
-                bp["attn"], _norm(cfg, bp["ln1"], x), positions, pc, pkr, pkp,
-                n_heads=cfg.n_heads, qk_nope=cfg.mla.qk_nope,
-                qk_rope=cfg.mla.qk_rope, v_dim=cfg.mla.v_dim,
-                rope_theta=cfg.rope_theta)
+            bp, pa, pb, pkp = xs[:4]
+            if stacks is not None:
+                bp = _with_experts(bp, xs[4], stacks)
+            h = _norm(cfg, bp["ln1"], x)
+            if cfg.mla is not None:
+                y, ta, tb = mla_extend(
+                    bp["attn"], h, positions, pa, pb, pkp,
+                    n_heads=cfg.n_heads, qk_nope=cfg.mla.qk_nope,
+                    qk_rope=cfg.mla.qk_rope, v_dim=cfg.mla.v_dim,
+                    rope=_mla_rope(cfg))
+            else:
+                y, ta, tb = attention_extend(
+                    bp["attn"], h, positions, pa, pb, pkp,
+                    n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
+                    rope_theta=None if cfg.pos == "none" else cfg.rope_theta)
             x = x + y
-            x = x + ffn_fn(bp, _norm(cfg, bp["ln2"], x))
-            return x, (c_t, kr_t)
+            x = x + _moe_or_swiglu(cfg, bp["ffn"], _norm(cfg, bp["ln2"], x),
+                                   dense_ffn)
+            return x, (ta, tb)
+        return body
 
-        x, outs = _scan(body, x, (blocks, past["c_kv"], past["k_rope"],
-                                  past["kpos"]), unroll)
-        tails = {"c_kv": outs[0], "k_rope": outs[1]}
-    else:
-        def body(x, xs):
-            bp, pk, pv, pkp = xs
-            y, k_t, v_t = attention_extend(
-                bp["attn"], _norm(cfg, bp["ln1"], x), positions, pk, pv, pkp,
-                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
-                rope_theta=None if cfg.pos == "none" else cfg.rope_theta)
-            x = x + y
-            x = x + ffn_fn(bp, _norm(cfg, bp["ln2"], x))
-            return x, (k_t, v_t)
-
-        x, outs = _scan(body, x, (blocks, past["k"], past["v"], past["kpos"]),
-                        unroll)
-        tails = {"k": outs[0], "v": outs[1]}
+    parts = []
+    for gblocks, lo, n, dense in _layer_groups(params, cfg):
+        gblocks, stacks = _split_experts(gblocks, dense)
+        xs = (gblocks,) + tuple(_rows(past[k], lo, n, cfg.n_layers)
+                                for k in names + ("kpos",))
+        if stacks is not None:
+            xs += (jnp.arange(n),)
+        x, outs = _scan(body_for(dense, stacks), x, xs, unroll)
+        parts.append(outs)
+    outs = _concat_layers(parts)
+    tails = {names[0]: outs[0], names[1]: outs[1]}
 
     h = x[jnp.arange(b), last][:, None]  # [B,1,d]
     h = _norm(cfg, params["final_ln"], h)

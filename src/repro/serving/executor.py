@@ -253,8 +253,8 @@ def _mesh_wrap(fn, b: int, *, batch_axes, out_axes, replicate=False,
     ``batch_axes``/``out_axes`` give the batch(-slot) axis position of each
     positional argument / output.  Stage buffers are trace-time constants and
     embed replicated per shard.  ``replicate=True`` (MoE plans) keeps the
-    batch axis unsplit: router rank and capacity are global-batch ops, so
-    slot-splitting would change the routing — every shard then computes the
+    batch axis unsplit: the router's ranks are global-batch ops, so
+    slot-splitting would change the dispatch — every shard then computes the
     identical full step, which still dodges the GSPMD partitioner that the
     interpreter-mode kernel cannot pass through.
     """
@@ -297,7 +297,7 @@ class StepPlan:
     super-stages* — "eg" (all experts' gates+ups, e-major ``[E*d] ->
     [2*E*dff]``) and "ed" (all downs, ``[E*dff] -> [E*d]``) — and the router
     weights ride along as a trace-time constant so the whole routed block
-    (softmax/top-k, capacity dispatch, SwiGLU, gated combine) runs *inside*
+    (softmax/top-k, drop-free dispatch, SwiGLU, gated combine) runs *inside*
     the single step launch.
 
     Under a device mesh, :meth:`decode_layers` wraps the kernel in
@@ -392,9 +392,7 @@ class StepPlan:
             stage_specs["ed"] = (ed, ne * edff, ne * d)
             self.moe = {"router": np.asarray(fb["router"], np.float32),
                         "n_experts": ne, "top_k": cfg.moe.top_k,
-                        "capacity_factor": cfg.moe.capacity_factor,
-                        "norm_topk": cfg.moe.norm_topk, "min_capacity": 4,
-                        "d_ff": ne * edff}
+                        "norm_topk": cfg.moe.norm_topk, "d_ff": ne * edff}
         pre = art.plans.get("step") if hasattr(art, "plans") else None
         if (pre is not None and set(pre) == set(stage_specs)
                 and all(ps.n_layers == cfg.n_layers for ps in pre.values())):
@@ -586,6 +584,8 @@ def _plan_ineligible_reason(cfg, has_sites: bool) -> str | None:
     """
     if getattr(cfg, "mla", None) is not None:
         return "mla"
+    if getattr(cfg, "n_dense_layers", 0):
+        return "leading_dense"  # the plan stacks L identical layers
     family = getattr(cfg, "family", "")
     if family in ("ssm", "hybrid"):
         return f"family:{family}"

@@ -50,7 +50,7 @@ def test_adapter_covers_three_families(dense_artifact):
     cfg_m = reduced_config(
         get_arch("mixtral-8x22b"), d_model=32, n_heads=2, n_kv_heads=2,
         head_dim=16, vocab=64, n_layers=1,
-        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16, capacity_factor=8.0))
+        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16))
     pm = api.init_params(jax.random.PRNGKey(1), cfg_m)
     art_m = api.compress_model(pm, cfg_m, _fp_compression())
     assert {"moe.gate.l0.e0", "moe.gate.l0.e1", "moe.down.l0.e0",

@@ -58,7 +58,7 @@ def test_moe_executor_parity_and_grouped_dispatch(monkeypatch):
     cfg = reduced_config(
         get_arch("mixtral-8x22b"), d_model=32, n_heads=2, n_kv_heads=2,
         head_dim=16, vocab=64, n_layers=1,
-        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16, capacity_factor=8.0))
+        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16))
     params = api.init_params(jax.random.PRNGKey(1), cfg)
     art = api.compress_model(params, cfg, _fp())
     cfg = art.config
@@ -103,9 +103,9 @@ def test_mla_executor_parity():
     """MLA projections (q/dkv/kr/uk/uv/o) route through fused chains —
     together with the MoE expert + shared-expert sites of the same layer."""
     cfg = reduced_config(get_arch("deepseek-v2-lite-16b"), d_model=32,
-                         n_heads=2, n_kv_heads=2, vocab=64, n_layers=1,
+                         n_heads=2, n_kv_heads=2, vocab=64, n_layers=2,
                          moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16,
-                                     n_shared=1, capacity_factor=8.0))
+                                     n_shared=1))
     params = api.init_params(jax.random.PRNGKey(4), cfg)
     art = api.compress_model(params, cfg, _fp())
     ex, err = _decode_parity(art)
@@ -164,7 +164,7 @@ def test_engine_serves_moe_artifact():
     cfg = reduced_config(
         get_arch("mixtral-8x22b"), d_model=32, n_heads=2, n_kv_heads=2,
         head_dim=16, vocab=64, n_layers=1,
-        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16, capacity_factor=8.0))
+        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16))
     params = api.init_params(jax.random.PRNGKey(1), cfg)
     art = api.compress_model(params, cfg, _fp())
     eng = ServingEngine(artifact=art, n_slots=2, max_len=32)
@@ -271,7 +271,7 @@ def test_compressed_adds_metric():
     cfg = reduced_config(
         get_arch("mixtral-8x22b"), d_model=32, n_heads=2, n_kv_heads=2,
         head_dim=16, vocab=64, n_layers=1,
-        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16, capacity_factor=8.0))
+        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16))
     params = api.init_params(jax.random.PRNGKey(1), cfg)
     art = api.compress_model(params, cfg, _fp())
     m = flops.compressed_adds(cfg, art)
@@ -356,7 +356,7 @@ def test_moe_plan_executor_parity():
     cfg = reduced_config(
         get_arch("mixtral-8x22b"), d_model=32, n_heads=2, n_kv_heads=2,
         head_dim=16, vocab=64, n_layers=2,
-        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16, capacity_factor=8.0))
+        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16))
     params = api.init_params(jax.random.PRNGKey(0), cfg)
     art = api.compress_model(params, cfg, _fp())
     cfg = art.config

@@ -162,7 +162,6 @@ def test_moe_manual_single_device_fallback():
     rng = np.random.default_rng(0)
     p = init_moe(jax.random.PRNGKey(0), 32, 16, 4, 1, jnp.float32)
     x = jnp.asarray(rng.standard_normal((2, 8, 32)), jnp.float32)
-    y0, _ = moe_ffn(p, x, n_experts=4, top_k=2, capacity_factor=8.0)
-    y1, _ = moe_ffn_manual(p, x, n_experts=4, top_k=2, capacity_factor=8.0,
-                           mesh=None)
+    y0, _ = moe_ffn(p, x, n_experts=4, top_k=2)
+    y1, _ = moe_ffn_manual(p, x, n_experts=4, top_k=2, mesh=None)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y1), rtol=1e-5, atol=1e-6)
