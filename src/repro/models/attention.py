@@ -54,43 +54,52 @@ class KVCache(NamedTuple):
 
 
 class PagedKVCache(NamedTuple):
-    """Paged KV: one block pool per layer plus per-row block tables.
+    """Paged KV: every layer's block pool plus per-row block tables.
 
-    ``k``/``v`` are the pool slice for this layer; ``tbl[b, j]`` names the
-    pool block backing row ``b``'s logical blocks (0 = the reserved null
-    block — unallocated, masked out via ``kpos == -1``).  The logical view
-    (``tbl`` gathered and flattened) has exactly the contiguous cache's
-    layout, so attention math — and its numerics — are unchanged."""
-    k: jnp.ndarray  # [Nb, bs, Hkv, Dh] block pool (this layer)
-    v: jnp.ndarray  # [Nb, bs, Hkv, Dh]
-    kpos: jnp.ndarray  # [B, S] logical positions (-1 = empty), S = mb * bs
+    ``k``/``v`` are the whole stack's pools, which the layer loop carries;
+    layer ``layer`` writes its new rows into them in place and reads its view
+    back.  ``tbl[b, j]`` names the pool block backing row ``b``'s logical
+    blocks (0 = the reserved null block — unallocated, masked out via
+    ``kpos == -1``).  The logical view (``tbl`` gathered and flattened) has
+    exactly the contiguous cache's layout, so attention math — and its
+    numerics — are unchanged."""
+    k: jnp.ndarray  # [L, Nb, bs, Hkv, Dh] block pools (all layers)
+    v: jnp.ndarray  # [L, Nb, bs, Hkv, Dh]
+    kpos: jnp.ndarray  # [B, S] this layer's positions (-1 = empty), S = mb * bs
     tbl: jnp.ndarray  # [B, mb] int32 block ids
+    layer: jnp.ndarray | int  # this layer's index into the pools
 
 
 class PagedMLACache(NamedTuple):
-    c_kv: jnp.ndarray  # [Nb, bs, dc] latent block pool (this layer)
-    k_rope: jnp.ndarray  # [Nb, bs, Dr]
+    c_kv: jnp.ndarray  # [L, Nb, bs, dc] latent block pools (all layers)
+    k_rope: jnp.ndarray  # [L, Nb, bs, Dr]
     kpos: jnp.ndarray  # [B, S]
     tbl: jnp.ndarray  # [B, mb]
+    layer: jnp.ndarray | int
 
 
-def paged_view(pool: jnp.ndarray, tbl: jnp.ndarray) -> jnp.ndarray:
-    """Gather a pool ``[Nb, bs, ...]`` through block tables ``[B, mb]`` into
-    the contiguous logical view ``[B, mb * bs, ...]``."""
+def paged_view(pool: jnp.ndarray, tbl: jnp.ndarray, layer) -> jnp.ndarray:
+    """Gather layer ``layer`` of the pools ``[L, Nb, bs, ...]`` through block
+    tables ``[B, mb]`` into the contiguous logical view ``[B, mb * bs, ...]``.
+
+    The barrier keeps XLA from moving the scores' f32 upcast of the view
+    above this reshape, onto the gather's own output: there the TPU compiler
+    fuses it into neither side and writes an f32 copy of the view a layer."""
     b, mb = tbl.shape
-    bs = pool.shape[1]
-    return pool[tbl].reshape(b, mb * bs, *pool.shape[2:])
+    bs = pool.shape[2]
+    view = pool[layer, tbl].reshape(b, mb * bs, *pool.shape[3:])
+    return jax.lax.optimization_barrier(view)
 
 
-def _paged_scatter(pool: jnp.ndarray, tbl: jnp.ndarray, slot: jnp.ndarray,
+def _paged_scatter(pool: jnp.ndarray, tbl: jnp.ndarray, layer, slot: jnp.ndarray,
                    vals: jnp.ndarray) -> jnp.ndarray:
-    """Write one token per row into the pool at logical view position
-    ``slot`` ([B], -1 = no write -> routed to the null block)."""
-    bs = pool.shape[1]
+    """Write one token per row into layer ``layer`` of the pools at logical
+    view position ``slot`` ([B], -1 = no write -> routed to the null block)."""
+    bs = pool.shape[2]
     w = jnp.maximum(slot, 0)
     bidx = jnp.take_along_axis(tbl, (w // bs)[:, None], axis=1)[:, 0]
     bidx = jnp.where(slot >= 0, bidx, 0)  # inactive rows sink to null block 0
-    return pool.at[bidx, w % bs].set(vals)
+    return pool.at[layer, bidx, w % bs].set(vals)
 
 
 def init_kv_cache(batch: int, smax: int, n_kv: int, head_dim: int, dtype) -> KVCache:
@@ -227,11 +236,11 @@ def attention_decode(
     input) — for sites named ``site.format(proj)``; uncovered sites stay
     dense.
 
-    ``cache`` may be a :class:`PagedKVCache`: keys/values then live in a block
-    pool indexed through per-row block tables.  The gathered logical view has
-    the contiguous layout (same positions, same mask math), and the new token
-    is additionally scattered into its pool block so the pool — not the view —
-    is the carried state."""
+    ``cache`` may be a :class:`PagedKVCache`: keys/values then live in block
+    pools indexed through per-row block tables.  The new token is scattered
+    into its pool block first and the view is gathered from the updated pool,
+    so the view holds it with no merge; the view has the contiguous layout
+    (same positions, same mask math)."""
     b = x.shape[0]
     paged = isinstance(cache, PagedKVCache)
     sn = site_fmt(site)
@@ -258,22 +267,23 @@ def attention_decode(
             k_new = apply_rope(k_new, pos[:, None], rope_theta)
         elif mrope_sections is not None:
             k_new = apply_mrope(k_new, mrope_positions, mrope_sections)
-        k_cur = paged_view(cache.k, cache.tbl) if paged else cache.k
-        v_cur = paged_view(cache.v, cache.tbl) if paged else cache.v
-        smax = k_cur.shape[1]
+        smax = cache.kpos.shape[1]
         # negative pos (serving's inactive-slot sentinel) must stay out of the
         # ring too: plain pos would wrap -1 % smax onto a live cache entry
         slot = jnp.where(pos >= 0, pos % smax, -1) if window is not None else pos
-        onehot = jax.nn.one_hot(slot, smax, dtype=k_cur.dtype)  # [B, Smax]
-        k = k_cur * (1 - onehot)[..., None, None] + onehot[..., None, None] * k_new
-        v = v_cur * (1 - onehot)[..., None, None] + onehot[..., None, None] * v_new
-        kpos = jnp.where(onehot > 0, pos[:, None], cache.kpos)
+        hit = jnp.arange(smax)[None] == slot[:, None]  # [B, Smax]
+        kpos = jnp.where(hit, pos[:, None], cache.kpos)
         if paged:
-            new_cache = PagedKVCache(
-                k=_paged_scatter(cache.k, cache.tbl, slot, k_new[:, 0]),
-                v=_paged_scatter(cache.v, cache.tbl, slot, v_new[:, 0]),
-                kpos=kpos, tbl=cache.tbl)
+            new_cache = cache._replace(
+                k=_paged_scatter(cache.k, cache.tbl, cache.layer, slot, k_new[:, 0]),
+                v=_paged_scatter(cache.v, cache.tbl, cache.layer, slot, v_new[:, 0]),
+                kpos=kpos)
+            k = paged_view(new_cache.k, cache.tbl, cache.layer)
+            v = paged_view(new_cache.v, cache.tbl, cache.layer)
         else:
+            onehot = hit.astype(cache.k.dtype)[..., None, None]
+            k = cache.k * (1 - onehot) + onehot * k_new
+            v = cache.v * (1 - onehot) + onehot * v_new
             new_cache = KVCache(k=k, v=v, kpos=kpos)
     g = n_heads // n_kv
     qg = q.reshape(b, 1, n_kv, g, head_dim)
@@ -439,28 +449,29 @@ def mla_prefill(p, x, positions, *, n_heads, kv_lora, qk_nope, qk_rope, v_dim,
 
 def mla_decode(p, x, cache: MLACache, pos, *, n_heads, kv_lora, qk_nope, qk_rope,
                v_dim, rope: MLARope, executor=None, site: str | None = None):
-    """``cache`` may be a :class:`PagedMLACache` — the latent/rope pools are
-    gathered through the block tables into the contiguous logical view and the
-    new latent is scattered back into its pool block (cf. attention_decode)."""
+    """``cache`` may be a :class:`PagedMLACache` — the new latent is
+    scattered into its pool block first and the contiguous logical view is
+    gathered from the updated pools (cf. attention_decode)."""
     b = x.shape[0]
     paged = isinstance(cache, PagedMLACache)
     sn = site_fmt(site)
-    c_cur = paged_view(cache.c_kv, cache.tbl) if paged else cache.c_kv
-    kr_cur = paged_view(cache.k_rope, cache.tbl) if paged else cache.k_rope
-    smax = c_cur.shape[1]
     c_new, kr_new = site_linear_group(executor, (sn("dkv"), sn("kr")),
                                       (p["dkv"], p["kr"]), x)  # [B,1,dc/Dr]
     c_new = rms_norm(c_new, p["kv_norm"])
-    onehot = jax.nn.one_hot(pos, smax, dtype=c_cur.dtype)
-    c_kv = c_cur * (1 - onehot)[..., None] + onehot[..., None] * c_new
-    k_rope = kr_cur * (1 - onehot)[..., None] + onehot[..., None] * kr_new
-    kpos = jnp.where(onehot > 0, pos[:, None], cache.kpos)
+    hit = jnp.arange(cache.kpos.shape[1])[None] == pos[:, None]
+    kpos = jnp.where(hit, pos[:, None], cache.kpos)
     if paged:
-        new_cache = PagedMLACache(
-            c_kv=_paged_scatter(cache.c_kv, cache.tbl, pos, c_new[:, 0]),
-            k_rope=_paged_scatter(cache.k_rope, cache.tbl, pos, kr_new[:, 0]),
-            kpos=kpos, tbl=cache.tbl)
+        new_cache = cache._replace(
+            c_kv=_paged_scatter(cache.c_kv, cache.tbl, cache.layer, pos, c_new[:, 0]),
+            k_rope=_paged_scatter(cache.k_rope, cache.tbl, cache.layer, pos,
+                                  kr_new[:, 0]),
+            kpos=kpos)
+        c_kv = paged_view(new_cache.c_kv, cache.tbl, cache.layer)
+        k_rope = paged_view(new_cache.k_rope, cache.tbl, cache.layer)
     else:
+        onehot = hit.astype(cache.c_kv.dtype)[..., None]
+        c_kv = cache.c_kv * (1 - onehot) + onehot * c_new
+        k_rope = cache.k_rope * (1 - onehot) + onehot * kr_new
         new_cache = MLACache(c_kv=c_kv, k_rope=k_rope, kpos=kpos)
 
     kpositions = jnp.maximum(kpos, 0)

@@ -550,6 +550,61 @@ def _unrolled_layers(body_for, x, xs_all, n_layers: int):
     return x, outs
 
 
+def _decode_layers(step_for, x, state, groups, caches, *, unrolled: bool,
+                   unroll: bool):
+    """The attention families' decode layer loop over ``groups`` of
+    ``(blocks, expert stacks or None, first layer, layers, dense_ffn)``.
+
+    A paged state's pools (``[L, Nb, bs, ...]``) ride in the carry whole,
+    next to ``x``: each layer scatters its B new rows into them at its own
+    index and gathers its view back, so under the engine's donation nothing
+    else writes the pools and the step's output aliases its input.  The other
+    per-layer leaves (``kpos``; all of a contiguous cache) are scanned as
+    xs/ys.  ``caches`` is ``(contiguous cache type, paged cache type)``;
+    ``step_for(li, dense_ffn)`` gives ``step(x, block, cache) -> (x, cache)``
+    for layer ``li`` — the absolute index on the ``unrolled`` path (the
+    executor's per-layer kernels), else None.
+    """
+    flat, paged = caches
+    tbl = state.get("block_tbl")
+    pools = ({} if tbl is None else
+             {k: state[k] for k in paged._fields
+              if k not in ("kpos", "tbl", "layer")})
+
+    def body_for(li, lo, dense, stacks):
+        step = step_for(li, dense)
+
+        def body(carry, xs):
+            (x, pools), (bp, per, i) = carry, xs
+            if stacks is not None:
+                bp = _with_experts(bp, i, stacks)
+            layer = lo + i if li is None else li
+            cache = (flat(**per) if tbl is None
+                     else paged(**pools, **per, tbl=tbl, layer=layer))
+            x, c2 = step(x, bp, cache)
+            out = c2._asdict()
+            return (x, {k: out[k] for k in pools}), {k: out[k] for k in per}
+        return body
+
+    parts = []
+    for gblocks, stacks, lo, n, dense in groups:
+        per = {k: _rows(a, lo, n, a.shape[0]) for k, a in state.items()
+               if k not in pools and k != "block_tbl"}
+        xs = (gblocks, per, jnp.arange(n))
+        if unrolled:
+            (x, pools), outs = _unrolled_layers(
+                lambda i, lo=lo, d=dense, st=stacks: body_for(lo + i, lo, d, st),
+                (x, pools), xs, n)
+        else:
+            (x, pools), outs = _scan(body_for(None, lo, dense, stacks),
+                                     (x, pools), xs, unroll)
+        parts.append(outs)
+    new = {**pools, **_concat_layers(parts)}
+    if tbl is not None:
+        new["block_tbl"] = tbl
+    return x, new
+
+
 def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = False,
                 executor=None):
     """One decode step: (logits [B, V], new state). token [B,1], pos [B].
@@ -687,19 +742,12 @@ def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = Fa
         new = {"ssm": ssm2, "conv": conv2, "attn_k": ak2, "attn_v": av2,
                "attn_kpos": akp2}
     elif cfg.mla is not None:
-        tbl = state.get("block_tbl")  # paged: closure constant across layers
         rope = _mla_rope(cfg)
 
-        def body_for(li, dense_ffn, stacks=None):
+        def step_for(li, dense_ffn):
             ex = executor if li is not None else None
 
-            def body(x, xs):
-                bp, ck, kr, kp = xs[:4]
-                if stacks is not None:
-                    bp = _with_experts(bp, xs[4], stacks)
-                cache = (PagedMLACache(c_kv=ck, k_rope=kr, kpos=kp, tbl=tbl)
-                         if tbl is not None
-                         else MLACache(c_kv=ck, k_rope=kr, kpos=kp))
+            def step(x, bp, cache):
                 y, c2 = mla_decode(
                     bp["attn"], _norm(cfg, bp["ln1"], x), cache, pos,
                     n_heads=cfg.n_heads, kv_lora=cfg.mla.kv_lora,
@@ -715,34 +763,21 @@ def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = Fa
                     kw = ({"executor": ex, "site_tag": f"l{li}"}
                           if ex is not None and not cfg.moe_manual else {})
                     y = _moe_or_swiglu(cfg, bp["ffn"], ffn_in, dense_ffn, **kw)
-                return x + y, (c2.c_kv, c2.k_rope, c2.kpos)
-            return body
+                return x + y, c2
+            return step
 
-        parts = []
-        for gblocks, lo, n, dense in _layer_groups(params, cfg):
-            xs_all = (gblocks,) + tuple(
-                _rows(state[k], lo, n, cfg.n_layers)
-                for k in ("c_kv", "k_rope", "kpos"))
-            if executor is None:
-                gb, stacks = _split_experts(gblocks, dense)
-                xs = (gb,) + xs_all[1:]
-                if stacks is not None:
-                    xs += (jnp.arange(n),)
-                x, outs = _scan(body_for(None, dense, stacks), x, xs, unroll)
-            else:
-                x, outs = _unrolled_layers(
-                    lambda li, lo=lo, d=dense: body_for(lo + li, d), x,
-                    xs_all, n)
-            parts.append(outs)
-        outs = _concat_layers(parts)
-        new = {"c_kv": outs[0], "k_rope": outs[1], "kpos": outs[2]}
-        if tbl is not None:
-            new["block_tbl"] = tbl
+        # the scan reads routed experts in place from their stacks; the
+        # unrolled path binds each layer's own sites
+        groups = [((gb, None) if executor is not None
+                   else _split_experts(gb, dense)) + (lo, n, dense)
+                  for gb, lo, n, dense in _layer_groups(params, cfg)]
+        x, new = _decode_layers(step_for, x, state, groups,
+                                (MLACache, PagedMLACache),
+                                unrolled=executor is not None, unroll=unroll)
     else:
         if cfg.n_dense_layers:
             raise NotImplementedError(
                 "leading dense layers are served with MLA attention only")
-        tbl = state.get("block_tbl")
         # whole-step layer plan: when the executor can express the full layer
         # stack as one stacked-grid launch, the per-layer loop (and all its
         # per-region dispatches) is replaced by a single pallas_call
@@ -752,7 +787,7 @@ def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = Fa
         if plan is not None:
             x, new = plan.decode_layers(state, x, pos)
         else:
-            def body_for(li):
+            def step_for(li, _dense_ffn):
                 ex = executor if li is not None else None
 
                 def ffn_fn(p, ffn_in):
@@ -764,10 +799,7 @@ def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = Fa
                         return _sites_swiglu(ex, f"ffn.{{}}.l{li}")(p, ffn_in)
                     return swiglu(p, ffn_in)
 
-                def body(x, xs):
-                    bp, k, v, kp = xs
-                    cache = (PagedKVCache(k=k, v=v, kpos=kp, tbl=tbl)
-                             if tbl is not None else KVCache(k=k, v=v, kpos=kp))
+                def step(x, bp, cache):
                     y, c2 = attention_decode(
                         bp["attn"], _norm(cfg, bp["ln1"], x), cache, pos,
                         n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
@@ -781,18 +813,15 @@ def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = Fa
                     x = x + y
                     ffn_in = _norm(cfg, bp["ln2"], x)
                     y = ffn_fn(bp["ffn"], ffn_in)
-                    return x + y, (c2.k, c2.v, c2.kpos)
-                return body
+                    return x + y, c2
+                return step
 
-            xs_all = (blocks, state["k"], state["v"], state["kpos"])
-            if executor is None:
-                x, outs = _scan(body_for(None), x, xs_all, unroll)
-            else:
-                # unrolled layer loop: each layer binds its own kernel buffers
-                x, outs = _unrolled_layers(body_for, x, xs_all, cfg.n_layers)
-            new = {"k": outs[0], "v": outs[1], "kpos": outs[2]}
-            if tbl is not None:
-                new["block_tbl"] = tbl
+            # unrolled layer loop with an executor: each layer binds its own
+            # kernel buffers
+            x, new = _decode_layers(step_for, x, state,
+                                    [(blocks, None, 0, cfg.n_layers, False)],
+                                    (KVCache, PagedKVCache),
+                                    unrolled=executor is not None, unroll=unroll)
 
     h = _norm(cfg, params["final_ln"], x)
     logits = logits_from_hidden(params, cfg, h)[:, 0]

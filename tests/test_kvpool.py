@@ -1,7 +1,8 @@
 """Paged KV-cache subsystem: block pool + prefix cache + continuous batching.
 
 Covers the host-side allocator (refcounts, eviction, all-or-nothing admission),
-paged-vs-contiguous decode parity (dense, MLA, windowed), prefix sharing with
+paged-vs-contiguous decode parity (dense, MLA with a leading dense layer,
+windowed, the compressed executor's unrolled loop), prefix sharing with
 copy-on-write under divergence, block lifecycle under cancel / exhaustion, and
 the scheduler's block-gated continuous admission."""
 import dataclasses
@@ -11,9 +12,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import core
 from repro.configs import get_arch, reduced_config
 from repro.models import api
 from repro.serving.engine import ServingEngine
+from repro.serving.executor import CompressedExecutor
 from repro.serving.kvpool import KVPool
 from repro.serving.scheduler import Scheduler
 
@@ -105,14 +108,41 @@ def _stepwise_logits(eng, prompt, n):
     return np.stack(rows)
 
 
-@pytest.mark.parametrize("family", ["dense", "mla", "windowed"])
-def test_paged_matches_contiguous_logits(family, dense_model, mla_model):
+def _executor_engines(monkeypatch, **kw):
+    """Contiguous and paged engines over a tiny LCC artifact, stepping
+    through the executor's per-layer (unrolled) loop: whole-step plans off,
+    as on the chip, where the kernels compile to Mosaic."""
+    monkeypatch.setattr(CompressedExecutor, "step_plan", lambda self, cfg: None)
+    cfg = reduced_config(get_arch("olmo-1b"), d_model=32, n_heads=2,
+                         n_kv_heads=2, head_dim=16, d_ff=48, vocab=64)
+    params = api.init_params(jax.random.PRNGKey(0), cfg)
+    art = api.compress_model(params, cfg, core.CompressionConfig(
+        algorithm="fp", weight_sharing=True, max_share_rel_err=0.06))
+    return (cfg, ServingEngine(artifact=art, kv_block=None, **kw),
+            ServingEngine(artifact=art, kv_block=16, **kw))
+
+
+# "mla": one leading dense layer, then one MoE layer; "mla-deep": two MoE
+# layers after it, so the MoE group's loop writes the pools at layers 1 and 2;
+# "executor": the compressed engine's unrolled layer loop
+@pytest.mark.parametrize("family", ["dense", "mla", "windowed", "mla-deep",
+                                    "executor"])
+def test_paged_matches_contiguous_logits(family, dense_model, mla_model,
+                                         monkeypatch):
     cfg, params = mla_model if family == "mla" else dense_model
     if family == "windowed":
         cfg = dataclasses.replace(cfg, attn_window=24)
+    if family == "mla-deep":
+        cfg = reduced_config(get_arch("deepseek-v2-lite-16b"), n_layers=3)
+        assert cfg.n_dense_layers == 1
+        params = api.init_params(jax.random.PRNGKey(0), cfg)
     kw = dict(n_slots=2, max_len=64)
-    ref = ServingEngine(params, cfg, kv_block=None, **kw)
-    pag = ServingEngine(params, cfg, kv_block=16, **kw)
+    if family == "executor":
+        cfg, ref, pag = _executor_engines(monkeypatch, **kw)
+        assert ref.executor is not None and pag.executor is not None
+    else:
+        ref = ServingEngine(params, cfg, kv_block=None, **kw)
+        pag = ServingEngine(params, cfg, kv_block=16, **kw)
     prompt = [(7 * i + 3) % cfg.vocab for i in range(24)]
     n = 6  # stays inside the admitted blocks (no growth in the raw loop)
     l_ref = _stepwise_logits(ref, prompt, n)
@@ -124,6 +154,8 @@ def test_paged_matches_contiguous_logits(family, dense_model, mla_model):
     r_pag = pag.generate([prompt, prompt[:13]], max_new_tokens=30)
     assert [r.tokens for r in r_ref] == [r.tokens for r in r_pag]
     assert pag.pool_stats()["in_use_blocks"] == 0
+    if family == "executor":
+        assert pag.executor.routed == pag.executor.sites
 
 
 # ------------------------------------------------- prefix sharing on device
