@@ -1,7 +1,7 @@
 """Compression-pipeline benchmark: offline Algorithm-1 throughput (units/sec
 and wall-clock) vs worker count, plus the content-addressed cache-hit
 speedup — emitted as machine-readable ``BENCH_compress.json`` so the offline
-path's perf trajectory is tracked across PRs like the serving loop's.
+path's perf trajectory is tracked across PRs.
 
     PYTHONPATH=src python benchmarks/bench_compress_pipeline.py [--smoke] [--out F]
 

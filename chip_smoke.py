@@ -286,7 +286,6 @@ def phase_compressed(cfg, *, seed: int, n_workers: int, n_slots: int = 4,
                        f"{missing[:8]}")
     launches = eng.pallas_launches_per_step
     check(launches > 0, "decode step emitted no Pallas launch")
-    fallbacks = eng.plan_stats()["fallbacks"]
     prompt = make_prompts(cfg.vocab, prompt_lens[:1], seed)[0]
     got, toks = cached_decode_logits(eng, prompt, probe_steps)
     err = rel_l2(got, reference_logits(art.params, cfg, toks))
@@ -296,7 +295,7 @@ def phase_compressed(cfg, *, seed: int, n_workers: int, n_slots: int = 4,
     return (f"width d{cfg.d_model} ff{cfg.d_ff} L{cfg.n_layers} compressed in "
             f"{compress_s:.1f}s (n_workers={n_workers}); {len(art.records)} "
             f"sites, {len(ex.routed & set(art.records))} routed, "
-            f"{launches} launches/step, plan fallbacks {fallbacks}; probe "
+            f"{launches} launches/step; probe "
             f"logits rel-L2 {err:.3g} <= {BF16_LOGIT_TOL}")
 
 

@@ -181,10 +181,6 @@ class CompressedModel:
     # applied globally.  ``unit_config_for`` is the read surface.
     unit_configs: dict[str, CompressionConfig] = field(default_factory=dict)
     pipeline_stats: dict = field(default_factory=dict)  # workers/cache/wall
-    # layer plans: plan key ("step", "moe:l3") -> {stage name -> PackedStage}.
-    # Built lazily by the executor on first decode and persisted so reloads
-    # skip the packing pass entirely.
-    plans: dict[str, dict] = field(default_factory=dict)
 
     def unit_config_for(self, name: str) -> CompressionConfig:
         return self.unit_configs.get(name, self.compression)
@@ -249,28 +245,6 @@ class CompressedModel:
                 "first_width": pk.first_width,
                 "chain_lengths": list(pk.chain_lengths),
             }
-        # layer plans: arrays per (plan key, stage name), presence + static
-        # ints in the manifest.  Optional manifest key — format version stays
-        # 1 and pre-plan artifacts load unchanged.
-        plans_tree: dict[str, Any] = {}
-        man_plans: dict[str, Any] = {}
-        # "segs" (segment-packed layout) is optional: PR 8-era artifacts
-        # without it load with segs=None and take the operand kernel path
-        _STAGE_ARRAYS = ("prep_src", "prep_tgt", "gidx", "gexp", "gsgn",
-                         "outg", "fs_mat", "dw_mat", "bias", "segs")
-        for pkey, stages in self.plans.items():
-            plans_tree[pkey] = {}
-            man_plans[pkey] = {}
-            for sname, ps in stages.items():
-                arrs = {f: np.asarray(getattr(ps, f)) for f in _STAGE_ARRAYS
-                        if getattr(ps, f) is not None}
-                plans_tree[pkey][sname] = arrs
-                man_plans[pkey][sname] = {
-                    "k_alloc": ps.k_alloc, "d_src": ps.d_src,
-                    "out_dim": ps.out_dim, "n_layers": ps.n_layers,
-                    "site_names": list(ps.site_names),
-                    "present": sorted(arrs),
-                }
         kind, cfg_dict = _config_to_manifest(self.config)
         manifest = {
             "version": _FORMAT_VERSION,
@@ -283,8 +257,6 @@ class CompressedModel:
             "units": man_units,
             "packed": man_packed,
         }
-        if man_plans:
-            manifest["plans"] = man_plans
         tree = {"manifest": np.frombuffer(
                     json.dumps(manifest).encode(), np.uint8).copy(),
                 "params": self.params}
@@ -294,8 +266,6 @@ class CompressedModel:
             tree["conv"] = conv_tree
         if packed_tree:
             tree["packed"] = packed_tree
-        if plans_tree:
-            tree["plans"] = plans_tree
         Checkpointer(directory).save(step, tree, blocking=True)
 
     # ------------------------------------------------------------------ load
@@ -369,27 +339,13 @@ class CompressedModel:
                 d_pad=int(pm["d_pad"]), first_width=int(pm["first_width"]),
                 chain_lengths=tuple(pm["chain_lengths"]),
             )
-        plans: dict[str, dict] = {}
-        for pkey, pstages in manifest.get("plans", {}).items():
-            from repro.kernels.ops import PackedStage
-
-            stages = {}
-            for sname, sm in pstages.items():
-                arrs = tree.get("plans", {}).get(pkey, {}).get(sname, {})
-                kw = {f: (np.asarray(arrs[f]) if f in sm["present"] else None)
-                      for f in ("prep_src", "prep_tgt", "gidx", "gexp",
-                                "gsgn", "outg", "fs_mat", "dw_mat", "bias",
-                                "segs")}
-                stages[sname] = PackedStage(
-                    k_alloc=int(sm["k_alloc"]), d_src=int(sm["d_src"]),
-                    out_dim=int(sm["out_dim"]), n_layers=int(sm["n_layers"]),
-                    site_names=tuple(sm["site_names"]), **kw)
-            plans[pkey] = stages
+        # a "plans" manifest section (and its "plans" arrays), written by
+        # older builds, is ignored: the executor packs nothing beyond
+        # ``packed``
         comp = CompressionConfig(**manifest["compression"])
         unit_configs = {n: CompressionConfig(**d)
                         for n, d in manifest.get("unit_configs", {}).items()}
         return cls(config=config, params=tree["params"], records=records,
                    packed=packed, report=_report_from_json(manifest["report"]),
                    compression=comp, unit_configs=unit_configs,
-                   pipeline_stats=manifest.get("pipeline_stats", {}),
-                   plans=plans)
+                   pipeline_stats=manifest.get("pipeline_stats", {}))

@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["param_pspec", "params_pspecs", "batch_pspecs", "decode_state_pspecs",
-           "named", "mesh_axis_size", "plan_batch_spec", "init_sharded"]
+           "named", "mesh_axis_size", "init_sharded"]
 
 
 def mesh_axis_size(mesh: Mesh, axis: str) -> int:
@@ -96,24 +96,6 @@ def _batch_axes(mesh: Mesh) -> tuple[str, ...] | str | None:
     if not axes:
         return None
     return axes if len(axes) > 1 else axes[0]
-
-
-def plan_batch_spec(mesh: Mesh, b: int):
-    """Mesh axis name(s) to split a layer plan's batch/slot axis over, or
-    None (replicate).  Mirrors :func:`decode_state_pspecs`'s slot rule —
-    ("pod","data") when the slot count divides the full extent, "data" alone
-    when only that divides — so the plan's ``shard_map`` sees the same local
-    slot partition the surrounding jitted step gives the KV cache."""
-    baxes = _batch_axes(mesh)
-    if baxes is None:
-        return None
-    bsize = int(np.prod([mesh_axis_size(mesh, a) for a in ("pod", "data")]))
-    dsize = mesh_axis_size(mesh, "data")
-    if bsize > 1 and b % bsize == 0 and b >= bsize:
-        return baxes
-    if dsize > 1 and b % dsize == 0 and b >= dsize:
-        return "data"
-    return None
 
 
 def batch_pspecs(batch_tree: Any, mesh: Mesh):

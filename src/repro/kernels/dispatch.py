@@ -34,8 +34,7 @@ def resolve_interpret(interpret: bool | None) -> bool:
 # per ``pallas_call`` that a trace emits (inner jit caches never hide a call
 # site: the un-jitted wrapper body runs on every trace-time invocation).
 # Within one jitted decode step the trace-time count equals the runtime
-# launches per step — the number the serving bench reports as
-# ``pallas_launches`` and the 1-launch-per-layer claim is measured against.
+# launches per step (``ServingEngine.pallas_launches_per_step``).
 
 _launch_count = 0
 _launch_metric = None  # lazily resolved obs counter (process-global registry)

@@ -166,8 +166,7 @@ def main() -> None:
         print(f"  live roofline: {live['total_lcc_adds']} lcc adds/token x "
               f"{live['decode_tok_s_n8']} tok/s = "
               f"{live['achieved_adds_per_s']} adds/s "
-              f"({live['pallas_launches']} launches / "
-              f"{live['n_layer_plans']} plans per step)")
+              f"({live['pallas_launches']} launches per step)")
     if args.trace_out:
         n_open = eng.tracer.dump_jsonl(args.trace_out)
         print(f"wrote {args.trace_out} ({tsum['completed']} spans, "

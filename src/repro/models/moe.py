@@ -121,16 +121,8 @@ def _routed_buffer(p, xt, gates, sel, n_experts: int, executor, site_tag):
         ys = fused([z[e].astype(jnp.float32).T for e in range(n_experts)])
         return jnp.stack([y.T for y in ys]).astype(z.dtype)
 
-    mp = getattr(executor, "moe_plan", None)
-    plan = (mp(site_tag, n_experts=n_experts, d_model=d,
-               d_ff=p["gate"].shape[-1]) if mp is not None else None)
-    if plan is not None:
-        # layer plan: all experts' gate/up/SwiGLU/down in ONE launch
-        out_buf = plan(buf)
-    else:
-        h = jax.nn.silu(expert_mm("gate", buf)) * expert_mm("up", buf)
-        out_buf = expert_mm("down", h)
-    out_buf = out_buf.reshape(n_experts * t, d)
+    h = jax.nn.silu(expert_mm("gate", buf)) * expert_mm("up", buf)
+    out_buf = expert_mm("down", h).reshape(n_experts * t, d)
     y = jnp.zeros((t, d), xt.dtype)
     for j in range(top_k):
         y = y + (gates[:, j].astype(xt.dtype)[:, None]

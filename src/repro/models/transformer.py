@@ -778,50 +778,42 @@ def decode_step(params, cfg: ArchConfig, state, token, pos, *, unroll: bool = Fa
         if cfg.n_dense_layers:
             raise NotImplementedError(
                 "leading dense layers are served with MLA attention only")
-        # whole-step layer plan: when the executor can express the full layer
-        # stack as one stacked-grid launch, the per-layer loop (and all its
-        # per-region dispatches) is replaced by a single pallas_call
-        plan = (executor.step_plan(cfg)
-                if executor is not None and hasattr(executor, "step_plan")
-                else None)
-        if plan is not None:
-            x, new = plan.decode_layers(state, x, pos)
-        else:
-            def step_for(li, _dense_ffn):
-                ex = executor if li is not None else None
 
-                def ffn_fn(p, ffn_in):
-                    if cfg.moe is not None:
-                        kw = ({"executor": ex, "site_tag": f"l{li}"}
-                              if ex is not None and not cfg.moe_manual else {})
-                        return _moe_or_swiglu(cfg, p, ffn_in, False, **kw)
-                    if ex is not None:
-                        return _sites_swiglu(ex, f"ffn.{{}}.l{li}")(p, ffn_in)
-                    return swiglu(p, ffn_in)
+        def step_for(li, _dense_ffn):
+            ex = executor if li is not None else None
 
-                def step(x, bp, cache):
-                    y, c2 = attention_decode(
-                        bp["attn"], _norm(cfg, bp["ln1"], x), cache, pos,
-                        n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
-                        window=cfg.attn_window,
-                        rope_theta=None if cfg.pos in ("none", "mrope") else cfg.rope_theta,
-                        mrope_sections=cfg.mrope_sections if cfg.pos == "mrope" else None,
-                        mrope_positions=jnp.broadcast_to(pos[None, :, None], (3, pos.shape[0], 1))
-                        if cfg.pos == "mrope" else None,
-                        executor=ex,
-                        site=f"attn.{{}}.l{li}" if ex is not None else None)
-                    x = x + y
-                    ffn_in = _norm(cfg, bp["ln2"], x)
-                    y = ffn_fn(bp["ffn"], ffn_in)
-                    return x + y, c2
-                return step
+            def ffn_fn(p, ffn_in):
+                if cfg.moe is not None:
+                    kw = ({"executor": ex, "site_tag": f"l{li}"}
+                          if ex is not None and not cfg.moe_manual else {})
+                    return _moe_or_swiglu(cfg, p, ffn_in, False, **kw)
+                if ex is not None:
+                    return _sites_swiglu(ex, f"ffn.{{}}.l{li}")(p, ffn_in)
+                return swiglu(p, ffn_in)
 
-            # unrolled layer loop with an executor: each layer binds its own
-            # kernel buffers
-            x, new = _decode_layers(step_for, x, state,
-                                    [(blocks, None, 0, cfg.n_layers, False)],
-                                    (KVCache, PagedKVCache),
-                                    unrolled=executor is not None, unroll=unroll)
+            def step(x, bp, cache):
+                y, c2 = attention_decode(
+                    bp["attn"], _norm(cfg, bp["ln1"], x), cache, pos,
+                    n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
+                    window=cfg.attn_window,
+                    rope_theta=None if cfg.pos in ("none", "mrope") else cfg.rope_theta,
+                    mrope_sections=cfg.mrope_sections if cfg.pos == "mrope" else None,
+                    mrope_positions=jnp.broadcast_to(pos[None, :, None], (3, pos.shape[0], 1))
+                    if cfg.pos == "mrope" else None,
+                    executor=ex,
+                    site=f"attn.{{}}.l{li}" if ex is not None else None)
+                x = x + y
+                ffn_in = _norm(cfg, bp["ln2"], x)
+                y = ffn_fn(bp["ffn"], ffn_in)
+                return x + y, c2
+            return step
+
+        # unrolled layer loop with an executor: each layer binds its own
+        # kernel buffers
+        x, new = _decode_layers(step_for, x, state,
+                                [(blocks, None, 0, cfg.n_layers, False)],
+                                (KVCache, PagedKVCache),
+                                unrolled=executor is not None, unroll=unroll)
 
     h = _norm(cfg, params["final_ln"], x)
     logits = logits_from_hidden(params, cfg, h)[:, 0]

@@ -17,8 +17,7 @@ opentelemetry):
   charged to the innermost open span.
 * :mod:`repro.obs.profile` — :class:`StepProfiler` wall-time ring buffer,
   plus the live roofline that ties an artifact's per-site shift-add budget
-  to the throughput a *running* engine achieves (the same table
-  ``BENCH_serving.json`` tracks offline).
+  to the throughput a *running* engine achieves.
 
 Dependency rule: ``obs`` imports nothing from the rest of ``repro`` (jax only
 lazily, for spans and the compile listener), so any layer — including

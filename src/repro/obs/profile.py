@@ -5,14 +5,11 @@ fused decode step.  The caller's clock must already include the device work:
 the serving engine reads its sampled tokens back inside the timed region,
 which syncs every step.
 
-:func:`roofline` is the pure function behind ``BENCH_serving.json``'s
-roofline section: per-site shift-add budget from an artifact's
-:class:`~repro.core.cost.ModelCostReport` joined with a measured decode
-throughput into achieved adds/s.  :func:`live_roofline` feeds it from a
-*running* engine — artifact from the executor, tok/s from the engine's own
-profiler, launch counts from the per-bucket registry — so the table no
-longer requires the offline bench path (ROADMAP Open item 1 asks exactly
-for this to localize the remaining gap to dense).
+:func:`roofline` joins the per-site shift-add budget of an artifact's
+:class:`~repro.core.cost.ModelCostReport` with a measured decode throughput
+into achieved adds/s.  :func:`live_roofline` feeds it from a *running*
+engine — artifact from the executor, tok/s from the engine's own profiler,
+launch counts from the per-bucket registry.
 """
 from __future__ import annotations
 
@@ -82,13 +79,8 @@ class StepProfiler:
 
 
 def roofline(artifact, decode_tok_s, *, pallas_launches=None,
-             n_layer_plans=None, mode: str | None = None,
-             arch: str | None = None) -> dict:
-    """Per-site shift-add budget x measured throughput -> achieved adds/s.
-
-    Same shape as the ``roofline`` sections in ``BENCH_serving.json``, so
-    live-engine output and offline-bench output diff cleanly.
-    """
+             mode: str | None = None, arch: str | None = None) -> dict:
+    """Per-site shift-add budget x measured throughput -> achieved adds/s."""
     rep = artifact.report
     total_lcc = rep.total_stage("lcc")
     tok_s = None if decode_tok_s is None else float(decode_tok_s)
@@ -98,7 +90,6 @@ def roofline(artifact, decode_tok_s, *, pallas_launches=None,
         "total_lcc_adds": total_lcc,
         "decode_tok_s_n8": round(tok_s, 2) if tok_s is not None else None,
         "pallas_launches": pallas_launches,
-        "n_layer_plans": n_layer_plans,
         "achieved_adds_per_s": (round(tok_s * total_lcc)
                                 if tok_s is not None else None),
         "sites": [{"site": l.name, "baseline_adds": l.baseline_adds,
@@ -115,9 +106,6 @@ def roofline(artifact, decode_tok_s, *, pallas_launches=None,
     waste = stats.get("padding_waste")
     if waste:
         sec["padding_waste"] = waste
-    seg = stats.get("segment_layout")
-    if seg:
-        sec["segment_layout"] = seg
     return sec
 
 
@@ -136,7 +124,6 @@ def live_roofline(engine) -> dict | None:
     sec = roofline(
         art, summ["tok_s"],
         pallas_launches=engine.pallas_launches_per_step,
-        n_layer_plans=engine.n_layer_plans,
         mode="live", arch=getattr(engine.cfg, "name", None))
     sec["profiler"] = summ
     return sec

@@ -158,8 +158,7 @@ class ServingEngine:
         self._next_req = 0
         self._prefill_fns: dict[int, object] = {}
         self.executor = (
-            self._build_executor(artifact, interpret, mesh) if use_kernel
-            else None)
+            self._build_executor(artifact, interpret) if use_kernel else None)
         ex = self.executor
         self._decode = jax.jit(
             lambda p, s, t, pos: api.decode(p, cfg, s, t, pos, executor=ex))
@@ -212,15 +211,6 @@ class ServingEngine:
             self._m_pool = m.gauge(
                 "serving_kv_pool", "KV block pool stats", labels=("stat",))
             m.gauge("serving_slots", "decode slots").set(n_slots)
-            # the executor builds layer plans lazily at first trace, so this
-            # gauge is refreshed alongside the launch counter at trace time
-            self._m_plans = m.gauge(
-                "serving_layer_plans", "distinct layer plans in the executor")
-            self._m_plans.set(self.n_layer_plans)
-            self._m_plan_fallbacks = m.counter(
-                "serving_plan_fallbacks_total",
-                "layer-plan builds that fell back to the per-region route",
-                labels=("reason",))
             # host seconds of each serving.* span, less its nested spans'
             # (repro.obs.spans); the scheduler's spans publish here too
             self.phases = m.counter(
@@ -237,9 +227,7 @@ class ServingEngine:
             self._m_steps = self._m_tokens = self._m_step_hist = None
             self._m_prefills = self._m_prefill_hist = self._m_launches = None
             self._m_grown = self._m_exhausted = self._m_pool = None
-            self._m_plans = self._m_plan_fallbacks = None
             self.phases = self._m_prefill_tok = None
-        self._fb_seen: set[str] = set()  # plan keys already counted
         self._step_fn = self._build_step_fn()
 
     def _init_state(self, init_fn):
@@ -252,15 +240,12 @@ class ServingEngine:
         return shd.init_sharded(init_fn, self.mesh, shd.decode_state_pspecs)
 
     @staticmethod
-    def _build_executor(artifact, interpret, mesh=None):
+    def _build_executor(artifact, interpret):
         """Site-keyed :class:`CompressedExecutor` over the artifact — family
-        agnostic (None when the artifact has no routable sites).  Layer plans
-        stay on under a mesh: the plan call wraps itself in ``shard_map``
-        (slot-split activations, replicated stage constants), so distributed
-        serving keeps the one-launch-per-plan step too."""
+        agnostic (None when the artifact has no routable sites)."""
         if artifact is None:
             return None
-        ex = CompressedExecutor(artifact, interpret=interpret, mesh=mesh)
+        ex = CompressedExecutor(artifact, interpret=interpret)
         return ex if ex.sites else None
 
     # ---------------------------------------------------------- fused step
@@ -294,8 +279,6 @@ class ServingEngine:
             self._trace_launches[bucket] = n_launch
             if self._m_launches is not None:
                 self._m_launches.set(n_launch, bucket=bucket)
-                self._m_plans.set(self.n_layer_plans)
-            self._sync_plan_fallbacks()
             sub = jax.vmap(jax.random.fold_in)(keys, new_count)
             nxt = api.sample_tokens(logits.astype(jnp.float32), sub, temps)
             nxt = jnp.where(emit, nxt, last_tok)
@@ -339,13 +322,6 @@ class ServingEngine:
         prefill, which runs dense)."""
         return max(self._trace_launches.values(), default=0)
 
-    @property
-    def n_layer_plans(self) -> int:
-        """Distinct layer plans the executor built for this engine."""
-        if self.executor is None:
-            return 0
-        return getattr(self.executor, "n_layer_plans", 0)
-
     # ------------------------------------------------------------------ API
     def validate_prompt(self, prompt: list[int]) -> str | None:
         """Why a prompt cannot be served (None when it can).  Single source of
@@ -369,29 +345,6 @@ class ServingEngine:
         if self.active.all():
             return False
         return self.pool is None or self.pool.can_admit(prompt)
-
-    def _sync_plan_fallbacks(self) -> None:
-        """Publish newly-recorded plan fallbacks (executor builds plans lazily
-        at trace time, so this runs alongside the launch accounting)."""
-        ex = self.executor
-        if ex is None:
-            return
-        for key, reason in getattr(ex, "plan_fallbacks", {}).items():
-            if key not in self._fb_seen:
-                self._fb_seen.add(key)
-                if self._m_plan_fallbacks is not None:
-                    self._m_plan_fallbacks.inc(1, reason=reason)
-
-    def plan_stats(self) -> dict:
-        """Layer-plan telemetry: plans built, measured launches per step, and
-        every plan key that fell back to the per-region route with its reason
-        string (``pool_stats()``-style — always the full key set)."""
-        self._sync_plan_fallbacks()
-        fallbacks = (dict(getattr(self.executor, "plan_fallbacks", {}))
-                     if self.executor is not None else {})
-        return {"n_layer_plans": self.n_layer_plans,
-                "pallas_launches_per_step": self.pallas_launches_per_step,
-                "fallbacks": fallbacks}
 
     def pool_stats(self) -> dict:
         """KV-pool telemetry.  Always the full key set — contiguous engines

@@ -261,10 +261,10 @@ def test_elastic_remesh_reshard_subprocess():
     assert "OK" in out
 
 
-def test_mesh_sharded_layer_plan_subprocess():
+def test_mesh_sharded_compressed_decode_subprocess():
     """Compressed artifact under a 2-device mesh decodes through the
-    whole-step layer plan (one launch per plan, shard_map-wrapped), with
-    tokens identical and logits within 1e-4 of the single-device engine."""
+    per-region LCC launches, with tokens identical and logits within 1e-4 of
+    the single-device engine, on both axis orders."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from repro import compat, core
@@ -282,8 +282,8 @@ def test_mesh_sharded_layer_plan_subprocess():
         prompts = [[5, 9, 2], [7, 1], [4, 4, 4, 8], [30]]
         ref = ServingEngine(artifact=art, n_slots=4, max_len=32)
         r_ref = ref.generate(prompts, max_new_tokens=6)
-        st_ref = ref.plan_stats()
-        assert st_ref["n_layer_plans"] == 1, st_ref
+        n_launch = ref.pallas_launches_per_step
+        assert n_launch > 0
 
         tok = jnp.asarray([[3], [1], [2], [7]], jnp.int32)
         pos = jnp.asarray([2, 1, 3, 0], jnp.int32)
@@ -295,18 +295,15 @@ def test_mesh_sharded_layer_plan_subprocess():
             eng = ServingEngine(artifact=art, n_slots=4, max_len=32,
                                 mesh=mesh)
             r = eng.generate(prompts, max_new_tokens=6)
-            st = eng.plan_stats()
-            assert st["n_layer_plans"] == 1, (axes, st)
-            assert st["pallas_launches_per_step"] == 1, (axes, st)
-            assert st["fallbacks"] == {}, (axes, st)
+            assert eng.pallas_launches_per_step == n_launch, axes
             assert [x.tokens for x in r] == [x.tokens for x in r_ref], axes
             stt = jax.device_put(st0, eng._state_sh)
             l_sh, _ = eng._decode(eng.params, stt, tok, pos)
             d = float(jnp.abs(l_ref.astype(jnp.float32)
                               - l_sh.astype(jnp.float32)).max())
             assert d <= 1e-4, (axes, d)
-            print("mesh", axes[0], "plans", st["n_layer_plans"],
-                  "launches", st["pallas_launches_per_step"], "max_diff", d)
+            print("mesh", axes[0], "launches", eng.pallas_launches_per_step,
+                  "max_diff", d)
         print("OK")
     """, devices=2)
     assert "OK" in out

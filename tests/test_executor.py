@@ -36,14 +36,38 @@ def _decode_parity(art, *, batch: int = 1, smax: int = 8):
     return ex, float(jnp.abs(l_k - l_d).max())
 
 
+def _two_step_parity(art, *, batch: int = 2, smax: int = 8):
+    """Two jitted decode steps on the kernel path vs the dense-effective
+    path: logits and the updated ``k``/``v``/``kpos`` leaves (when the state
+    has them) within 1e-4 at each step, the second step run from each path's
+    own first-step state.  Returns the executor."""
+    cfg = art.config
+    ex = CompressedExecutor(art, interpret=None)
+    s_k = s_d = api.init_decode_state(cfg, batch, smax)
+    for step, t in enumerate((3, 5)):
+        tok = jnp.asarray([[t]] * batch, jnp.int32)
+        pos = jnp.asarray([step] * batch, jnp.int32)
+        l_k, s_k = jax.jit(lambda p, st: api.decode(
+            p, cfg, st, tok, pos, executor=ex))(art.params, s_k)
+        l_d, s_d = jax.jit(lambda p, st: api.decode(
+            p, cfg, st, tok, pos))(art.params, s_d)
+        assert float(jnp.abs(l_k - l_d).max()) <= 1e-4, step
+        for leaf in ("k", "v", "kpos"):
+            if leaf in s_d:
+                d = jnp.abs(s_k[leaf].astype(jnp.float32)
+                            - s_d[leaf].astype(jnp.float32))
+                assert float(d.max()) <= 1e-4, (step, leaf)
+    return ex
+
+
 # ------------------------------------------------------------ family parity
 
 
-def test_moe_executor_parity_and_grouped_dispatch(monkeypatch):
+@pytest.mark.parametrize("n_layers", [1, 2])
+def test_moe_executor_parity_and_grouped_dispatch(n_layers, monkeypatch):
     """All experts of an MoE layer apply their chains through the grouped
-    (one-dispatch) launch; compressed logits match dense-effective <= 1e-4.
-    Plans are disabled here so the per-region grouped route stays covered
-    (with plans on, the whole-step MoE plan absorbs the expert dispatches)."""
+    (one-dispatch) launch; compressed logits and KV leaves match
+    dense-effective <= 1e-4 over two decode steps."""
     from repro.kernels import ops
 
     calls = {"group": 0}
@@ -57,22 +81,13 @@ def test_moe_executor_parity_and_grouped_dispatch(monkeypatch):
 
     cfg = reduced_config(
         get_arch("mixtral-8x22b"), d_model=32, n_heads=2, n_kv_heads=2,
-        head_dim=16, vocab=64, n_layers=1,
+        head_dim=16, vocab=64, n_layers=n_layers,
         moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16))
-    params = api.init_params(jax.random.PRNGKey(1), cfg)
+    params = api.init_params(jax.random.PRNGKey(n_layers - 1), cfg)
     art = api.compress_model(params, cfg, _fp())
-    cfg = art.config
-    ex = CompressedExecutor(art, interpret=None, use_plans=False)
-    state = api.init_decode_state(cfg, 2, 8)
-    tok = jnp.asarray([[3]] * 2, jnp.int32)
-    pos = jnp.asarray([0] * 2, jnp.int32)
-    l_k, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos,
-                                          executor=ex))(art.params)
-    l_d, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos))(art.params)
-    assert float(jnp.abs(l_k - l_d).max()) <= 1e-4
+    ex = _two_step_parity(art)
     assert ex.routed == ex.sites, ex.sites - ex.routed
     assert calls["group"] > 0, "MoE experts never hit the grouped launch"
-    assert ex.plan_fallbacks.get("step") == "plans_disabled"
 
 
 def test_rwkv6_executor_parity():
@@ -283,132 +298,119 @@ def test_compressed_adds_metric():
     assert m["active_ratio"] > 1.0
 
 
-# --------------------------------------------------------------- layer plans
+# ------------------------------------------------- per-region decode route
 
 
-def test_step_plan_decode_parity_all_sites():
-    """Whole-step layer plan == per-region kernels == dense-effective decode
-    (<= 1e-4), with every site routed and exactly one plan built."""
-    cfg = reduced_config(get_arch("olmo-1b"), d_model=32, n_heads=2,
-                         n_kv_heads=2, head_dim=16, d_ff=48, vocab=64,
-                         n_layers=2)
-    params = api.init_params(jax.random.PRNGKey(0), cfg)
-    art = api.compress_model(params, cfg, _fp())
-    batch, smax = 2, 8
-    state = api.init_decode_state(cfg, batch, smax)
-    tok = jnp.asarray([[3]] * batch, jnp.int32)
-    pos = jnp.asarray([0] * batch, jnp.int32)
-
-    ex_plan = CompressedExecutor(art, interpret=None)
-    ex_reg = CompressedExecutor(art, interpret=None, use_plans=False)
-    run = lambda ex: jax.jit(
-        lambda p: api.decode(p, cfg, state, tok, pos, executor=ex))(art.params)
-    l_plan, s_plan = run(ex_plan)
-    l_reg, _ = run(ex_reg)
-    l_d, s_d = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos))(art.params)
-
-    assert float(jnp.abs(l_plan - l_d).max()) <= 1e-4
-    assert float(jnp.abs(l_plan - l_reg).max()) <= 1e-4
-    assert ex_plan.n_layer_plans == 1
-    assert ex_plan.routed == ex_plan.sites
-    for leaf in ("k", "v", "kpos"):  # KV write-back outside the kernel
-        d = jnp.abs(s_plan[leaf].astype(jnp.float32)
-                    - s_d[leaf].astype(jnp.float32))
-        assert float(d.max()) <= 1e-4, leaf
-    # a second step from the plan-updated state keeps tracking dense
-    tok2 = jnp.asarray([[5]] * batch, jnp.int32)
-    pos2 = jnp.asarray([1] * batch, jnp.int32)
-    l2p, _ = jax.jit(lambda p: api.decode(p, cfg, s_plan, tok2, pos2,
-                                          executor=ex_plan))(art.params)
-    l2d, _ = jax.jit(lambda p: api.decode(p, cfg, s_d, tok2, pos2))(art.params)
-    assert float(jnp.abs(l2p - l2d).max()) <= 1e-4
+def _with_random_biases(params, seed: int = 7):
+    """Give every q/k/v bias random values (they initialise to zero), so a
+    site whose bias the kernel route drops cannot pass parity."""
+    rng = np.random.default_rng(seed)
+    attn = dict(params["blocks"]["attn"])
+    for proj in ("q", "k", "v"):
+        if "b" in attn[proj]:
+            b = attn[proj]["b"]
+            attn[proj] = {**attn[proj], "b": jnp.asarray(
+                0.1 * rng.standard_normal(b.shape), b.dtype)}
+    return {**params, "blocks": {**params["blocks"], "attn": attn}}
 
 
-def test_step_plan_bakes_uncovered_sites_dense():
-    """An FFN-only artifact still gets a whole-step plan: attention q/k/v/o
-    ride along as baked dense blocks, and the plan builds lazily inside the
-    jitted trace without touching traced params."""
-    cfg = reduced_config(get_arch("olmo-1b"), d_model=32, n_heads=2,
-                         n_kv_heads=2, head_dim=16, d_ff=48, vocab=64,
-                         n_layers=2)
-    params = api.init_params(jax.random.PRNGKey(0), cfg)
-    art = api.compress_model(params, cfg, _fp(),
-                             include=lambda n: n.startswith("ffn."))
-    assert all(n.startswith("ffn.") for n in art.records)
-    ex = CompressedExecutor(art, interpret=None)
-    state = api.init_decode_state(cfg, 2, 8)
-    tok = jnp.asarray([[3]] * 2, jnp.int32)
-    pos = jnp.asarray([0] * 2, jnp.int32)
-    l_k, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos,
-                                          executor=ex))(art.params)
-    l_d, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos))(art.params)
-    assert ex.n_layer_plans == 1  # the lazy in-trace build must not fall back
-    assert float(jnp.abs(l_k - l_d).max()) <= 1e-4
+# (arch, sites): every site of olmo-1b; its FFN sites only (attention stays
+# dense beside the LCC launches); llama3.2-3b's grouped KV heads; qwen2.5-3b's
+# q/k/v biases
+@pytest.mark.parametrize("arch,include", [
+    ("olmo-1b", None), ("olmo-1b", "ffn."), ("llama3.2-3b", None),
+    ("qwen2.5-3b", None)], ids=["olmo-1b", "olmo-1b-ffn", "llama3.2-3b",
+                                "qwen2.5-3b"])
+def test_decode_parity_two_steps(arch, include):
+    """The per-region LCC route (one launch per q/k/v, o, gate/up and down
+    region) tracks dense-effective decode <= 1e-4 over two steps, KV leaves
+    included, and routes every compressed site."""
+    base = get_arch(arch)
+    n_kv = 2 if base.n_kv_heads < base.n_heads else 4
+    cfg = reduced_config(base, d_model=32, n_heads=4, n_kv_heads=n_kv,
+                         head_dim=8, d_ff=48, vocab=64, n_layers=2)
+    assert (cfg.n_kv_heads < cfg.n_heads) == (arch != "olmo-1b")
+    params = _with_random_biases(api.init_params(jax.random.PRNGKey(0), cfg))
+    assert ("b" in params["blocks"]["attn"]["q"]) == (arch == "qwen2.5-3b")
+    art = api.compress_model(params, cfg, _fp(), include=include)
+    if include is not None:
+        assert art.records and all(n.startswith(include) for n in art.records)
+    ex = _two_step_parity(art)
     assert ex.routed == ex.sites == set(art.records)
 
 
-def test_moe_plan_executor_parity():
-    """Whole-step MoE plan (attention + router top-k + both expert
-    super-stages in ONE launch) == per-region grouped kernels ==
-    dense-effective decode, including a second decode step."""
-    from repro.kernels import dispatch
-
-    cfg = reduced_config(
-        get_arch("mixtral-8x22b"), d_model=32, n_heads=2, n_kv_heads=2,
-        head_dim=16, vocab=64, n_layers=2,
-        moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16))
-    params = api.init_params(jax.random.PRNGKey(0), cfg)
-    art = api.compress_model(params, cfg, _fp())
-    cfg = art.config
-    state = api.init_decode_state(cfg, 2, 8)
-    tok = jnp.asarray([[3]] * 2, jnp.int32)
-    pos = jnp.asarray([0] * 2, jnp.int32)
-    ex_plan = CompressedExecutor(art, interpret=None)
-    ex_reg = CompressedExecutor(art, interpret=None, use_plans=False)
-    run = lambda ex: jax.jit(
-        lambda p: api.decode(p, cfg, state, tok, pos, executor=ex))(art.params)
-    dispatch.reset_launch_count()
-    t0 = dispatch.launch_count()
-    l_plan, s_plan = run(ex_plan)
-    n_launch = dispatch.launch_count() - t0
-    l_reg, _ = run(ex_reg)
-    l_d, s_d = jax.jit(lambda p: api.decode(p, cfg, state, tok,
-                                            pos))(art.params)
-    assert float(jnp.abs(l_plan - l_d).max()) <= 1e-4
-    assert float(jnp.abs(l_plan - l_reg).max()) <= 1e-4
-    # the routed block folds into the step plan: launches == plans == 1
-    assert ex_plan.n_layer_plans == 1
-    assert n_launch == 1, n_launch
-    assert ex_plan.plan_fallbacks == {}
-    assert ex_plan.routed == ex_plan.sites
-    # second decode step from the plan-updated state keeps tracking dense
-    tok2 = jnp.asarray([[5]] * 2, jnp.int32)
-    pos2 = jnp.asarray([1] * 2, jnp.int32)
-    l2p, _ = jax.jit(lambda p: api.decode(p, cfg, s_plan, tok2, pos2,
-                                          executor=ex_plan))(art.params)
-    l2d, _ = jax.jit(lambda p: api.decode(p, cfg, s_d, tok2, pos2))(art.params)
-    assert float(jnp.abs(l2p - l2d).max()) <= 1e-4
+@pytest.mark.parametrize("n_layers", [1, 2])
+@pytest.mark.parametrize("kv_block", [16, None], ids=["paged", "contiguous"])
+def test_engine_kernel_tokens_and_launches(n_layers, kv_block):
+    """Engine level: kernel tokens == dense-effective tokens, and an
+    all-sites dense artifact's fused decode step emits four LCC launches a
+    layer (q/k/v, o, gate/up, down), as the chip's step does."""
+    cfg = reduced_config(get_arch("olmo-1b"), d_model=32, n_heads=2,
+                         n_kv_heads=2, head_dim=16, d_ff=48, vocab=64,
+                         n_layers=n_layers)
+    params = api.init_params(jax.random.PRNGKey(1), cfg)
+    comp = core.CompressionConfig(algorithm="fp", weight_sharing=False)
+    art = api.compress_model(params, cfg, comp)
+    eng_k = ServingEngine(artifact=art, n_slots=2, max_len=32,
+                          kv_block=kv_block)
+    eng_d = ServingEngine(artifact=art, n_slots=2, max_len=32,
+                          kv_block=kv_block, use_kernel=False)
+    prompts = [[5, 9, 2, 7], [3, 1]]
+    out_k = eng_k.generate(prompts, max_new_tokens=6, temperature=0.0)
+    out_d = eng_d.generate(prompts, max_new_tokens=6, temperature=0.0)
+    assert [r.tokens for r in out_k] == [r.tokens for r in out_d]
+    assert eng_k.executor.routed == eng_k.executor.sites
+    assert eng_k.pallas_launches_per_step == 4 * n_layers
 
 
-def test_engine_step_plan_single_launch():
-    """Engine-level (paged KV): plan tokens == dense tokens AND the measured
-    Pallas launches per fused decode step equals the number of layer plans."""
-    from repro.serving.engine import ServingEngine
+def test_artifact_with_legacy_plans_section_loads_and_decodes(tmp_path):
+    """Artifacts written by older builds carry a ``"plans"`` manifest section
+    and ``plans/...`` arrays; loading skips both, and the artifact decodes
+    bitwise like the same artifact saved without them."""
+    import json
+
+    from repro.checkpoint.checkpointer import Checkpointer
+    from repro.core.artifact import _unflatten
 
     cfg = reduced_config(get_arch("olmo-1b"), d_model=32, n_heads=2,
                          n_kv_heads=2, head_dim=16, d_ff=48, vocab=64,
                          n_layers=2)
-    params = api.init_params(jax.random.PRNGKey(1), cfg)
+    params = api.init_params(jax.random.PRNGKey(0), cfg)
     art = api.compress_model(params, cfg, _fp())
-    eng_k = ServingEngine(artifact=art, n_slots=2, max_len=32)
-    eng_d = ServingEngine(artifact=art, n_slots=2, max_len=32,
-                          use_kernel=False)
-    prompt = [5, 9, 2, 7]
-    out_k = eng_k.generate([prompt], max_new_tokens=8, temperature=0.0)
-    out_d = eng_d.generate([prompt], max_new_tokens=8, temperature=0.0)
-    assert [r.tokens for r in out_k] == [r.tokens for r in out_d]
-    assert eng_k.n_layer_plans == 1
-    assert eng_k.pallas_launches_per_step == eng_k.n_layer_plans == 1
+    plain = str(tmp_path / "plain")
+    art.save(plain)
+
+    flat = Checkpointer(plain).restore_flat(0)
+    man = json.loads(np.asarray(flat["manifest"], np.uint8).tobytes())
+    assert "plans" not in man
+    man["plans"] = {"step": {"qkv": {
+        "k_alloc": 33, "d_src": 32, "out_dim": 64, "n_layers": 2,
+        "site_names": ["attn.q.l0", "attn.k.l0", "attn.v.l0"],
+        "present": ["gidx", "gsgn"]}}}
+    flat["manifest"] = np.frombuffer(json.dumps(man).encode(), np.uint8).copy()
+    flat["plans/step/qkv/gidx"] = np.zeros((2, 1, 8, 2), np.int32)
+    flat["plans/step/qkv/gsgn"] = np.ones((2, 1, 8, 2), np.int8)
+    legacy = str(tmp_path / "legacy")
+    Checkpointer(legacy).save(0, _unflatten(flat), blocking=True)
+    assert "plans/step/qkv/gidx" in Checkpointer(legacy).restore_flat(0)
+
+    art_p, art_l = CompressedModel.load(plain), CompressedModel.load(legacy)
+    assert set(art_l.records) == set(art_p.records)
+    assert set(art_l.packed) == set(art_p.packed)
+    state = api.init_decode_state(cfg, 2, 8)
+    tok = jnp.asarray([[3]] * 2, jnp.int32)
+    pos = jnp.asarray([0] * 2, jnp.int32)
+
+    def run(a):
+        ex = CompressedExecutor(a, interpret=None)
+        logits, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos,
+                                                 executor=ex))(a.params)
+        return ex, np.asarray(logits)
+
+    ex_l, l_l = run(art_l)
+    _, l_p = run(art_p)
+    np.testing.assert_array_equal(l_l, l_p)
+    assert ex_l.routed == ex_l.sites == set(art.records)
 
 
 def test_pack_group_padding_waste_reported():
@@ -436,7 +438,7 @@ def test_pack_group_padding_waste_reported():
                          n_layers=1)
     params = api.init_params(jax.random.PRNGKey(0), cfg)
     art = api.compress_model(params, cfg, _fp())
-    ex = CompressedExecutor(art, interpret=None, use_plans=False)
+    ex = CompressedExecutor(art, interpret=None)
     state = api.init_decode_state(cfg, 1, 8)
     tok = jnp.asarray([[3]], jnp.int32)
     pos = jnp.asarray([0], jnp.int32)
@@ -445,142 +447,3 @@ def test_pack_group_padding_waste_reported():
     pw = art.pipeline_stats.get("padding_waste", {})
     assert pw, "grouped regions must record their padding waste"
     assert all(0.0 <= v["mean_row_waste"] <= 1.0 for v in pw.values())
-
-
-def test_artifact_plans_roundtrip(tmp_path):
-    """Packed layer-plan stages persist through save/load, and a fresh
-    executor on the loaded artifact reuses them (same decode numerics)."""
-    cfg = reduced_config(get_arch("olmo-1b"), d_model=32, n_heads=2,
-                         n_kv_heads=2, head_dim=16, d_ff=48, vocab=64,
-                         n_layers=2)
-    params = api.init_params(jax.random.PRNGKey(0), cfg)
-    art = api.compress_model(params, cfg, _fp())
-    ex = CompressedExecutor(art, interpret=None)
-    assert ex.step_plan(cfg) is not None  # builds + stores into art.plans
-    assert "step" in art.plans
-
-    d = str(tmp_path / "plan_art")
-    art.save(d)
-    art2 = CompressedModel.load(d)
-    assert "step" in art2.plans
-    for name, ps in art.plans["step"].items():
-        ps2 = art2.plans["step"][name]
-        assert ps2.k_alloc == ps.k_alloc and ps2.out_dim == ps.out_dim
-        for f in ("prep_src", "prep_tgt", "gidx", "gexp", "gsgn", "outg",
-                  "fs_mat", "dw_mat", "bias", "segs"):
-            a, b = getattr(ps, f), getattr(ps2, f)
-            assert (a is None) == (b is None), (name, f)
-            if a is not None:
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    ex2 = CompressedExecutor(art2, interpret=None)
-    state = api.init_decode_state(cfg, 1, 8)
-    tok = jnp.asarray([[3]], jnp.int32)
-    pos = jnp.asarray([0], jnp.int32)
-    l1, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos,
-                                         executor=ex))(art.params)
-    l2, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos,
-                                         executor=ex2))(art2.params)
-    assert ex2.n_layer_plans == 1
-    np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
-                               rtol=1e-6, atol=1e-6)
-
-
-def test_pr8_plan_artifacts_without_segs_load_and_decode_bitwise(tmp_path):
-    """PR 8-era saved plans carry no segment-packed layout: stripping ``segs``
-    before save must load back with ``segs is None`` and decode through the
-    original full-gather operand path bitwise-identically to the in-memory
-    stripped plan."""
-    import dataclasses
-
-    cfg = reduced_config(get_arch("olmo-1b"), d_model=32, n_heads=2,
-                         n_kv_heads=2, head_dim=16, d_ff=48, vocab=64,
-                         n_layers=2)
-    params = api.init_params(jax.random.PRNGKey(0), cfg)
-    art = api.compress_model(params, cfg, _fp())
-    ex = CompressedExecutor(art, interpret=None)
-    assert ex.step_plan(cfg) is not None
-    # simulate a PR 8 artifact: drop the segment descriptors before saving
-    art.plans["step"] = {n: dataclasses.replace(ps, segs=None, seg_stats=None,
-                                                waste=None)
-                         for n, ps in art.plans["step"].items()}
-    d = str(tmp_path / "pr8_art")
-    art.save(d)
-    art2 = CompressedModel.load(d)
-    assert all(ps.segs is None for ps in art2.plans["step"].values())
-
-    state = api.init_decode_state(cfg, 2, 8)
-    tok = jnp.asarray([[3]] * 2, jnp.int32)
-    pos = jnp.asarray([0] * 2, jnp.int32)
-    ex_mem = CompressedExecutor(art, interpret=None)  # reuses stripped stages
-    ex_load = CompressedExecutor(art2, interpret=None)
-    l_mem, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos,
-                                            executor=ex_mem))(art.params)
-    l_load, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos,
-                                             executor=ex_load))(art2.params)
-    assert ex_load.n_layer_plans == 1  # legacy layout still plans
-    np.testing.assert_array_equal(np.asarray(l_mem), np.asarray(l_load))
-    # and the operand path still tracks dense within tolerance
-    l_d, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos))(art.params)
-    assert float(jnp.abs(l_load - l_d).max()) <= 1e-4
-
-
-def test_plan_fallback_reasons_and_segment_stats():
-    """Ineligible families record a reason string in ``plan_fallbacks``;
-    eligible plans record per-stage padding waste and segment-layout
-    run-length stats into the artifact's pipeline_stats."""
-    # hybrid family: step plan must fall back with a reason, not silently
-    cfg_hyb = reduced_config(get_arch("zamba2-7b"), d_model=64, n_heads=4,
-                             n_kv_heads=4, head_dim=16, d_ff=96, vocab=64,
-                             ssm=SSMSpec(d_inner=64, d_state=16, head_dim=16,
-                                         d_conv=4))
-    params = api.init_params(jax.random.PRNGKey(0), cfg_hyb)
-    art = api.compress_model(params, cfg_hyb, _fp())
-    ex = CompressedExecutor(art, interpret=None)
-    assert ex.step_plan(cfg_hyb) is None
-    assert ex.plan_fallbacks.get("step") == "family:hybrid"
-
-    # eligible dense family: stages carry segs + stats, recorded in the art
-    cfg = reduced_config(get_arch("olmo-1b"), d_model=32, n_heads=2,
-                         n_kv_heads=2, head_dim=16, d_ff=48, vocab=64,
-                         n_layers=2)
-    params = api.init_params(jax.random.PRNGKey(1), cfg)
-    art = api.compress_model(params, cfg, _fp())
-    ex = CompressedExecutor(art, interpret=None)
-    plan = ex.step_plan(cfg)
-    assert plan is not None and ex.plan_fallbacks == {}
-    segged = [ps for ps in plan.stages.values() if ps.segs is not None]
-    assert segged, "new plans must carry segment descriptors"
-    seg = art.pipeline_stats.get("segment_layout", {})
-    pw = art.pipeline_stats.get("padding_waste", {})
-    assert any(k.startswith("plan.") for k in seg), seg
-    assert any(k.startswith("plan.") for k in pw), pw
-    for st in seg.values():
-        assert st["p50_run_after"] >= st["p50_run_before"] or \
-            st["n_runs_after"] <= st["n_runs_before"]
-        assert 0.0 <= st["gather_frac"] <= 1.0
-    for wv in (v for k, v in pw.items() if k.startswith("plan.")):
-        assert 0.0 <= wv["row_waste"] <= 1.0
-        assert 0.0 <= wv["slice_waste"] <= 1.0
-
-
-def test_engine_plan_stats_and_fallback_metric():
-    """Engine telemetry: ``plan_stats()`` reports plans/launches/fallback
-    reasons and ``serving_plan_fallbacks_total{reason}`` counts each plan
-    key once."""
-    from repro.serving.engine import ServingEngine
-
-    cfg = reduced_config(get_arch("zamba2-7b"), d_model=64, n_heads=4,
-                         n_kv_heads=4, head_dim=16, d_ff=96, vocab=64,
-                         ssm=SSMSpec(d_inner=64, d_state=16, head_dim=16,
-                                     d_conv=4))
-    params = api.init_params(jax.random.PRNGKey(0), cfg)
-    art = api.compress_model(params, cfg, _fp())
-    eng = ServingEngine(artifact=art, n_slots=2, max_len=16)
-    eng.generate([[5, 9]], max_new_tokens=4, temperature=0.0)
-    st = eng.plan_stats()
-    assert st["n_layer_plans"] == 0
-    assert st["fallbacks"].get("step") == "family:hybrid"
-    assert "pallas_launches_per_step" in st
-    metric = eng.metrics.to_prometheus()
-    assert 'serving_plan_fallbacks_total{reason="family:hybrid"} 1' in metric

@@ -428,33 +428,37 @@ def test_group_prox_zero_rows_boundary_unaligned():
 
 
 # ---------------------------------------------------------------------------
-# CSD shift-add layer-plan stage vs ref oracles (bitwise)
+# CSD shift-add chains through lcc_chain_matmul vs ref oracles (bitwise)
 # ---------------------------------------------------------------------------
 
-def _csd_stage(idx, exp, sgn, k_in):
-    """Hand-build a 1-layer PackedStage around a raw CSD chain [P, R, S]."""
-    from repro.kernels.ops import PackedStage
+def _csd_chain(idx, exp, sgn, x, *, use_gather):
+    """Apply a raw CSD chain [P, R, S] to x [K, B] in one ``lcc_chain_matmul``
+    launch: the interpreter's gather body, or the compiled row gather run
+    under the interpreter (with its first factor as one-hot tiles)."""
+    from repro.kernels.lcc_chain_matmul import lcc_chain_matmul
 
-    p, r, s = idx.shape
-    return PackedStage(
-        prep_src=np.arange(k_in, dtype=np.int32)[None],
-        prep_tgt=np.arange(k_in, dtype=np.int32)[None],
-        gidx=np.asarray(idx, np.int32)[None],
-        gexp=np.asarray(exp, np.int8)[None],
-        gsgn=np.asarray(sgn, np.int8)[None],
-        outg=np.arange(r, dtype=np.int32)[None, None],
-        fs_mat=None, dw_mat=None, bias=None,
-        k_alloc=k_in + 1, d_src=k_in, out_dim=r, n_layers=1,
-        site_names=("synthetic",))
+    idx, exp, sgn = (np.asarray(a, t)[None]
+                     for a, t in ((idx, np.int32), (exp, np.int8),
+                                  (sgn, np.int8)))
+    k, b = x.shape
+    rows = -(-max(k, idx.shape[2]) // 8) * 8
+    xp = np.zeros((1, rows, b), np.float32)
+    xp[0, :k] = x
+    y = lcc_chain_matmul(idx, exp, sgn, jnp.asarray(xp), block_b=b,
+                         first_width=k, interpret=True, use_gather=use_gather,
+                         terms=None if use_gather else pack_terms(idx, exp,
+                                                                  sgn))
+    return np.asarray(y)
 
 
-def test_stage_matmul_csd_shift_add_bitwise_vs_ref():
-    """The one-launch CSD shift-add stage matches the densify-then-matmul
-    oracle BITWISE: every operand is a signed power of two times an integer
-    input, all intermediates are dyadic rationals far inside the f32 mantissa,
-    so both evaluation orders are exact and must agree to the last bit."""
-    from repro.kernels import layer_plan
-
+@pytest.mark.parametrize("use_gather", [True, False],
+                         ids=["gather", "row_gather"])
+def test_chain_matmul_csd_shift_add_bitwise_vs_ref(use_gather):
+    """A CSD shift-add chain matches the densify-then-matmul oracle BITWISE
+    on both kernel bodies: every operand is a signed power of two times an
+    integer input, all intermediates are dyadic rationals far inside the f32
+    mantissa, so both evaluation orders are exact and must agree to the last
+    bit."""
     rng = np.random.default_rng(11)
     k_in, r, p, s, b = 8, 8, 3, 2, 5
     idx = rng.integers(0, k_in, (p, r, s))
@@ -467,43 +471,19 @@ def test_stage_matmul_csd_shift_add_bitwise_vs_ref():
                 jnp.asarray(sgn[q], jnp.int8)) for q in range(p)]
     want = np.asarray(ref.lcc_chain_apply_ref(factors, jnp.asarray(x)))
 
-    ps = _csd_stage(idx, exp, sgn, k_in)
-    got = np.asarray(layer_plan.stage_matmul(ps, jnp.asarray(x)[None]))[0]
+    got = _csd_chain(idx, exp, sgn, x, use_gather=use_gather)[:r]
     np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got[2], 0.0)
 
 
-@pytest.mark.parametrize("p", [3, 4])
-def test_fuse_csd_levels_bitwise(p):
-    """Level fusion composes signed powers of two exactly: the fused stage
-    must agree bitwise with both the unfused stage and the ref chain, for an
-    even level count (full pairwise fusion) and an odd one (unfused tail)."""
-    from repro.kernels import layer_plan
-
-    rng = np.random.default_rng(100 + p)
-    k_in, r, s, b = 8, 8, 2, 4
-    idx = rng.integers(0, k_in, (p, r, s))
-    exp = rng.integers(-2, 3, (p, r, s))
-    sgn = rng.choice([-1, 0, 1], (p, r, s))
-    sgn[0, 5] = 0  # dead parent row: fused terms through it must go dead too
-    x = np.asarray(rng.integers(-4, 5, (k_in, b)), np.float32)
-
-    factors = [(jnp.asarray(idx[q], jnp.int32), jnp.asarray(exp[q], jnp.int8),
-                jnp.asarray(sgn[q], jnp.int8)) for q in range(p)]
-    want = np.asarray(ref.lcc_chain_apply_ref(factors, jnp.asarray(x)))
-
-    fi, fe, fs = ops._fuse_csd_levels(idx, exp, sgn)
-    assert fi.shape[0] == (p + 1) // 2  # depth halved (odd tail rides along)
-    got = np.asarray(layer_plan.stage_matmul(
-        _csd_stage(fi, fe, fs, k_in), jnp.asarray(x)[None]))[0]
-    np.testing.assert_array_equal(got, want)
-
-
-def test_stage_matmul_csd_digits_reproduce_constants():
-    """A 1-level stage built from ``csd_digits`` of real coefficients applies
-    exactly c * x: shift-add reconstruction of a CSD-coded scalar is bitwise
-    identical to the direct multiply for dyadic c and integer x."""
+@pytest.mark.parametrize("use_gather", [True, False],
+                         ids=["gather", "row_gather"])
+def test_chain_matmul_csd_digits_reproduce_constants(use_gather):
+    """A one-factor chain built from ``csd_digits`` of real coefficients
+    applies exactly c * x on both kernel bodies: shift-add reconstruction of
+    a CSD-coded scalar is bitwise identical to the direct multiply for dyadic
+    c and integer x."""
     from repro.core.csd import csd_digits
-    from repro.kernels import layer_plan
 
     consts = [2.5, -3.75, 0.625, 1.0]
     digits = [csd_digits(c) for c in consts]
@@ -518,7 +498,6 @@ def test_stage_matmul_csd_digits_reproduce_constants():
 
     rng = np.random.default_rng(3)
     x = np.asarray(rng.integers(-8, 9, (1, 6)), np.float32)
-    got = np.asarray(layer_plan.stage_matmul(
-        _csd_stage(idx, exp, sgn, 1), jnp.asarray(x)[None]))[0]
+    got = _csd_chain(idx, exp, sgn, x, use_gather=use_gather)[:r]
     want = np.asarray(consts, np.float32)[:, None] * x
     np.testing.assert_array_equal(got, want)

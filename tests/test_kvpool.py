@@ -16,7 +16,6 @@ from repro import core
 from repro.configs import get_arch, reduced_config
 from repro.models import api
 from repro.serving.engine import ServingEngine
-from repro.serving.executor import CompressedExecutor
 from repro.serving.kvpool import KVPool
 from repro.serving.scheduler import Scheduler
 
@@ -108,11 +107,9 @@ def _stepwise_logits(eng, prompt, n):
     return np.stack(rows)
 
 
-def _executor_engines(monkeypatch, **kw):
+def _executor_engines(**kw):
     """Contiguous and paged engines over a tiny LCC artifact, stepping
-    through the executor's per-layer (unrolled) loop: whole-step plans off,
-    as on the chip, where the kernels compile to Mosaic."""
-    monkeypatch.setattr(CompressedExecutor, "step_plan", lambda self, cfg: None)
+    through the executor's per-layer (unrolled) loop."""
     cfg = reduced_config(get_arch("olmo-1b"), d_model=32, n_heads=2,
                          n_kv_heads=2, head_dim=16, d_ff=48, vocab=64)
     params = api.init_params(jax.random.PRNGKey(0), cfg)
@@ -127,8 +124,7 @@ def _executor_engines(monkeypatch, **kw):
 # "executor": the compressed engine's unrolled layer loop
 @pytest.mark.parametrize("family", ["dense", "mla", "windowed", "mla-deep",
                                     "executor"])
-def test_paged_matches_contiguous_logits(family, dense_model, mla_model,
-                                         monkeypatch):
+def test_paged_matches_contiguous_logits(family, dense_model, mla_model):
     cfg, params = mla_model if family == "mla" else dense_model
     if family == "windowed":
         cfg = dataclasses.replace(cfg, attn_window=24)
@@ -138,7 +134,7 @@ def test_paged_matches_contiguous_logits(family, dense_model, mla_model,
         params = api.init_params(jax.random.PRNGKey(0), cfg)
     kw = dict(n_slots=2, max_len=64)
     if family == "executor":
-        cfg, ref, pag = _executor_engines(monkeypatch, **kw)
+        cfg, ref, pag = _executor_engines(**kw)
         assert ref.executor is not None and pag.executor is not None
     else:
         ref = ServingEngine(params, cfg, kv_block=None, **kw)
