@@ -12,8 +12,8 @@ from __future__ import annotations
 import jax
 
 __all__ = ["default_interpret", "resolve_interpret", "record_launch",
-           "record_lcc_launch", "record_moe_launch", "launch_count",
-           "reset_launch_count"]
+           "record_lcc_launch", "record_moe_launch",
+           "record_mla_decode_route", "launch_count", "reset_launch_count"]
 
 
 def default_interpret() -> bool:
@@ -40,6 +40,7 @@ _launch_count = 0
 _launch_metric = None  # lazily resolved obs counter (process-global registry)
 _lcc_metric = None
 _moe_metric = None
+_mla_route_metric = None
 
 
 def record_launch(n: int = 1) -> None:
@@ -90,6 +91,23 @@ def record_moe_launch(launch: str, body: str) -> None:
             "grouped expert launches recorded at trace time, by launch and "
             "body", labels=("launch", "body"))
     _moe_metric.inc(launch=launch, body=body)
+
+
+def record_mla_decode_route(route: str) -> None:
+    """Count one traced MLA decode layer under the ``route`` it took:
+    ``absorbed`` (``uk``/``uv`` folded into the query and the output, the
+    latent scored directly) or ``expanded`` (an executor binds ``uk`` or
+    ``uv``, so the latent is expanded to per-head keys and values).
+    Published as ``mla_decode_routes_total{route}`` in the process-global
+    registry, at trace time like :func:`record_launch`."""
+    global _mla_route_metric
+    if _mla_route_metric is None:
+        from repro.obs import get_global
+        _mla_route_metric = get_global().counter(
+            "mla_decode_routes_total",
+            "MLA decode layers recorded at trace time, by route",
+            labels=("route",))
+    _mla_route_metric.inc(route=route)
 
 
 def launch_count() -> int:

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.act_shard import constrain
+from repro.kernels.dispatch import record_mla_decode_route
 
 from .layers import (apply_mrope, apply_rope, apply_rope_pairs, dense_init,
                      linear, rms_norm, rope_inv_freq, site_fmt, site_linear,
@@ -378,6 +379,18 @@ def init_mla(key, d_model: int, n_heads: int, *, kv_lora: int, qk_nope: int,
     }
 
 
+def _mla_q(p, x, positions, n_heads, qk_nope, qk_rope, rope: MLARope,
+           executor=None, site=None):
+    """The queries' two parts: ``q_nope`` [B,S,H,qk_nope] and the rotated
+    ``q_rope`` [B,S,H,Dr]."""
+    b, s, _ = x.shape
+    q = site_linear(executor, site_fmt(site)("q"), p["q"], x).reshape(
+        b, s, n_heads, qk_nope + qk_rope)
+    q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
+    return q_nope, apply_rope_pairs(q_rope, positions, rope.inv_freq,
+                                    rope.cos_scale)
+
+
 def _mla_qkv(p, x, c_kv, k_rope_src, positions, kpositions, n_heads, qk_nope, qk_rope, v_dim,
              rope: MLARope, executor=None, site=None):
     """``c_kv`` is the normed latent (as cached); ``k_rope_src`` the rotary
@@ -385,10 +398,8 @@ def _mla_qkv(p, x, c_kv, k_rope_src, positions, kpositions, n_heads, qk_nope, qk
     b, s, _ = x.shape
     sk = c_kv.shape[1]
     sn = site_fmt(site)
-    q = site_linear(executor, sn("q"), p["q"], x).reshape(
-        b, s, n_heads, qk_nope + qk_rope)
-    q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
-    q_rope = apply_rope_pairs(q_rope, positions, rope.inv_freq, rope.cos_scale)
+    q_nope, q_rope = _mla_q(p, x, positions, n_heads, qk_nope, qk_rope, rope,
+                            executor, site)
     # uk/uv share the latent-cache input: one grouped launch when compressed
     uk, uv = site_linear_group(executor, (sn("uk"), sn("uv")),
                                (p["uk"], p["uv"]), c_kv)
@@ -451,7 +462,12 @@ def mla_decode(p, x, cache: MLACache, pos, *, n_heads, kv_lora, qk_nope, qk_rope
                v_dim, rope: MLARope, executor=None, site: str | None = None):
     """``cache`` may be a :class:`PagedMLACache` — the new latent is
     scattered into its pool block first and the contiguous logical view is
-    gathered from the updated pools (cf. attention_decode)."""
+    gathered from the updated pools (cf. attention_decode).
+
+    The heads attend to the latent directly (:func:`_mla_absorbed`) unless
+    ``executor`` binds ``uk`` or ``uv`` as compressed chains: the latent is
+    then expanded to per-head keys and values through them, as prefill
+    does.  Each trace counts its route in ``mla_decode_routes_total``."""
     b = x.shape[0]
     paged = isinstance(cache, PagedMLACache)
     sn = site_fmt(site)
@@ -475,15 +491,54 @@ def mla_decode(p, x, cache: MLACache, pos, *, n_heads, kv_lora, qk_nope, qk_rope
         new_cache = MLACache(c_kv=c_kv, k_rope=k_rope, kpos=kpos)
 
     kpositions = jnp.maximum(kpos, 0)
-    q, k, v = _mla_qkv(p, x, c_kv, k_rope, pos[:, None], kpositions, n_heads,
-                       qk_nope, qk_rope, v_dim, rope,
-                       executor=executor, site=site)
-    qg = q.reshape(b, 1, n_heads, 1, qk_nope + qk_rope)
     valid = (kpos >= 0) & (kpos <= pos[:, None])
-    mask = jnp.where(valid, 0.0, _NEG)[:, None, None, None, :]
-    out = _sdpa(qg, k, v, mask, rope.softmax_scale)
+    # a chain bound to uk or uv has no transposed form to fold into the query
+    if executor is None or (executor.matvec(sn("uk")) is None
+                            and executor.matvec(sn("uv")) is None):
+        record_mla_decode_route("absorbed")
+        out = _mla_absorbed(p, x, c_kv, k_rope, pos, kpositions, valid,
+                            n_heads, qk_nope, qk_rope, v_dim, rope,
+                            executor, site)
+    else:
+        record_mla_decode_route("expanded")
+        q, k, v = _mla_qkv(p, x, c_kv, k_rope, pos[:, None], kpositions,
+                           n_heads, qk_nope, qk_rope, v_dim, rope,
+                           executor=executor, site=site)
+        qg = q.reshape(b, 1, n_heads, 1, qk_nope + qk_rope)
+        mask = jnp.where(valid, 0.0, _NEG)[:, None, None, None, :]
+        out = _sdpa(qg, k, v, mask, rope.softmax_scale)
     out = out.reshape(b, 1, n_heads * v_dim)
     return site_linear(executor, sn("o"), p["o"], out.astype(x.dtype)), new_cache
+
+
+def _mla_absorbed(p, x, c_kv, k_rope_src, pos, kpositions, valid, n_heads,
+                  qk_nope, qk_rope, v_dim, rope: MLARope, executor, site):
+    """One decode token's attention on the latent itself (DeepSeek-V2,
+    arXiv:2405.04434 §2.1.3): ``q_nope · (c W_uk)ᵀ = (q_nope W_ukᵀ) · cᵀ``
+    and ``probs · (c W_uv) = (probs · c) W_uv``, so no per-head key or value
+    over the cache is formed and the rotary term scores the one shared
+    rotary key.  The two contractions over the cache read the view ``c_kv``
+    [B,T,dc] and the rotary keys in their own dtype and accumulate in f32.
+    Returns the heads' outputs [B,H,v_dim] in f32."""
+    dc, dt, f32 = c_kv.shape[-1], c_kv.dtype, jnp.float32
+    q_nope, q_rope = _mla_q(p, x, pos[:, None], n_heads, qk_nope, qk_rope,
+                            rope, executor, site)
+    w_uk = p["uk"]["w"].reshape(dc, n_heads, qk_nope)
+    w_uv = p["uv"]["w"].reshape(dc, n_heads, v_dim)
+    k_rope = apply_rope_pairs(k_rope_src[:, :, None, :], kpositions,
+                              rope.inv_freq, rope.cos_scale)[:, :, 0]
+    q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_uk,
+                       preferred_element_type=f32)
+    scores = (jnp.einsum("bhc,btc->bht", q_lat.astype(dt), c_kv,
+                         preferred_element_type=f32)
+              + jnp.einsum("bhr,btr->bht", q_rope[:, 0].astype(dt), k_rope,
+                           preferred_element_type=f32))
+    scores = scores * rope.softmax_scale + jnp.where(valid, 0.0, _NEG)[:, None]
+    probs = jax.nn.softmax(scores, axis=-1)
+    o_lat = jnp.einsum("bht,btc->bhc", probs.astype(dt), c_kv,
+                       preferred_element_type=f32)
+    return jnp.einsum("bhc,che->bhe", o_lat.astype(w_uv.dtype), w_uv,
+                      preferred_element_type=f32)
 
 
 def mla_extend(p, x, positions, past_c, past_kr, past_kpos, *, n_heads,

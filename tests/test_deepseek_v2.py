@@ -97,6 +97,83 @@ def test_program_matches_reference(tiny):
             np.abs(rows - want).max(), scale)
 
 
+def _expanded_mla_decode(p, x, cache, pos, *, n_heads, kv_lora, qk_nope,
+                         qk_rope, v_dim, rope, executor=None, site=None):
+    """The oracle: MLA decode as the published formula states it, the
+    cached latent expanded through ``uk``/``uv`` to per-head keys and values
+    over every position, then attention over them."""
+    from repro.models import attention as at
+
+    b = x.shape[0]
+    c_new = at.rms_norm(at.linear(p["dkv"], x), p["kv_norm"])
+    kr_new = at.linear(p["kr"], x)
+    hit = jnp.arange(cache.kpos.shape[1])[None] == pos[:, None]
+    kpos = jnp.where(hit, pos[:, None], cache.kpos)
+    if isinstance(cache, at.PagedMLACache):
+        new_cache = cache._replace(
+            c_kv=at._paged_scatter(cache.c_kv, cache.tbl, cache.layer, pos,
+                                   c_new[:, 0]),
+            k_rope=at._paged_scatter(cache.k_rope, cache.tbl, cache.layer,
+                                     pos, kr_new[:, 0]),
+            kpos=kpos)
+        c_kv = at.paged_view(new_cache.c_kv, cache.tbl, cache.layer)
+        k_rope = at.paged_view(new_cache.k_rope, cache.tbl, cache.layer)
+    else:
+        onehot = hit.astype(cache.c_kv.dtype)[..., None]
+        c_kv = cache.c_kv * (1 - onehot) + onehot * c_new
+        k_rope = cache.k_rope * (1 - onehot) + onehot * kr_new
+        new_cache = at.MLACache(c_kv=c_kv, k_rope=k_rope, kpos=kpos)
+    q, k, v = at._mla_qkv(p, x, c_kv, k_rope, pos[:, None],
+                          jnp.maximum(kpos, 0), n_heads, qk_nope, qk_rope,
+                          v_dim, rope)
+    valid = (kpos >= 0) & (kpos <= pos[:, None])
+    mask = jnp.where(valid, 0.0, at._NEG)[:, None, None, None, :]
+    out = at._sdpa(q.reshape(b, 1, n_heads, 1, qk_nope + qk_rope), k, v,
+                   mask, rope.softmax_scale)
+    return (at.linear(p["o"], out.reshape(b, 1, n_heads * v_dim)
+                      .astype(x.dtype)), new_cache)
+
+
+@pytest.mark.parametrize("kv_block", [None, 16])
+def test_absorbed_decode_matches_expanded(tiny, kv_block, monkeypatch):
+    """The engine's decode attends to the cached latent directly (``uk``
+    folded into the query, ``uv`` applied after the probabilities weight
+    the latent).  Over 8 steps of two partly filled slots beside an empty
+    one (``kpos`` all -1, fed position -1), contiguous and paged, its
+    logits match the expanded formula's <= 1e-4: both compute in float32
+    and differ only in summation order."""
+    from repro.models import transformer
+
+    _, spec, params, cfg = tiny
+    prompts = [[(7 * i + 3) % spec.vocab for i in range(21)],
+               [(5 * i + 1) % spec.vocab for i in range(9)]]
+
+    def logits():
+        eng = ServingEngine(params, cfg, n_slots=3, max_len=64,
+                            kv_block=kv_block)
+        rids = [eng.submit(p) for p in prompts]
+        slots = [next(s for s, r in eng.slot_req.items() if r == rid)
+                 for rid in rids]
+        st, rows = eng.state, []
+        for step in range(8):
+            tok = np.zeros((3, 1), np.int32)
+            pos = np.full(3, -1, np.int32)
+            for j, (s, p) in enumerate(zip(slots, prompts)):
+                tok[s, 0] = (11 * step + 3 * j + 1) % spec.vocab
+                pos[s] = len(p) + step
+            out, st = eng._decode(eng.params, st, jnp.asarray(tok),
+                                  jnp.asarray(pos))
+            rows.append(np.asarray(out, np.float32))
+        return np.stack(rows)
+
+    absorbed = logits()
+    monkeypatch.setattr(transformer, "mla_decode", _expanded_mla_decode)
+    expanded = logits()
+    assert np.isfinite(absorbed).all()
+    assert np.abs(absorbed - expanded).max() <= 1e-4, (
+        np.abs(absorbed - expanded).max(), np.abs(expanded).max())
+
+
 def test_routing_is_drop_free(monkeypatch):
     """Every token of a batch of 64 picks the same 3 of 8 experts (a rigged
     router): capacity dispatch at factor 1.25 would keep 30 rows an expert

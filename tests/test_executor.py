@@ -128,6 +128,57 @@ def test_mla_executor_parity():
     assert ex.routed == ex.sites, ex.sites - ex.routed
 
 
+def _mla_routes(fn):
+    """The ``mla_decode_routes_total`` counts that tracing ``fn`` adds."""
+    from repro.obs import get_global
+
+    def counts():
+        m = get_global().get("mla_decode_routes_total")
+        return {} if m is None else {r["labels"]["route"]: r["value"]
+                                     for r in m.values()}
+
+    before = counts()
+    fn()
+    return {k: v - before.get(k, 0.0) for k, v in counts().items()
+            if v != before.get(k, 0.0)}
+
+
+# "dense": no executor; "no_uk_uv": an executor over every site but the
+# latent's up-projections; "all": an executor that binds uk/uv as chains
+@pytest.mark.parametrize("bound,route", [("dense", "absorbed"),
+                                         ("no_uk_uv", "absorbed"),
+                                         ("all", "expanded")])
+def test_mla_decode_route_follows_executor(bound, route):
+    """MLA decode attends to the cached latent directly unless the executor
+    binds ``uk`` or ``uv``: a chain has no transposed form to fold into the
+    query, so the latent is then expanded through it.  The route is counted
+    at trace time; either way the step matches dense decode <= 1e-4."""
+    cfg = reduced_config(get_arch("deepseek-v2-lite-16b"), d_model=32,
+                         n_heads=2, n_kv_heads=2, vocab=64, n_layers=2,
+                         moe=MoESpec(n_experts=2, top_k=1, d_ff_expert=16,
+                                     n_shared=1))
+    params = api.init_params(jax.random.PRNGKey(4), cfg)
+    state = api.init_decode_state(cfg, 1, 8)
+    tok, pos = jnp.asarray([[3]], jnp.int32), jnp.asarray([0], jnp.int32)
+    ex = None
+    if bound != "dense":
+        keep = (None if bound == "all" else
+                lambda n: not n.startswith(("attn.uk.", "attn.uv.")))
+        art = api.compress_model(params, cfg, _fp(), include=keep)
+        params, ex = art.params, CompressedExecutor(art, interpret=None)
+        assert any(n.startswith("attn.q.") for n in ex.sites)
+        assert (bound == "all") == any(n.startswith("attn.uk.")
+                                       for n in ex.sites)
+    step = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos, executor=ex))
+    routes = _mla_routes(lambda: step.trace(params))
+    assert set(routes) == {route} and routes[route] > 0, routes
+    if ex is not None:
+        l_d, _ = jax.jit(lambda p: api.decode(p, cfg, state, tok, pos))(params)
+        err = float(jnp.abs(step(params)[0] - l_d).max())
+        assert err <= 1e-4, err
+        assert any(n.startswith("attn.q.") for n in ex.routed), ex.routed
+
+
 def test_whisper_executor_parity():
     """Whisper decoder self/cross-attention + MLP sites run fused; encoder
     and cross-KV sites only execute at prefill, so the decode step routes

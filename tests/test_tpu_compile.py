@@ -200,7 +200,10 @@ def test_decode_step_writes_pool_in_place(one_chip, family):
     the layer loop's scatter and gather take it row-major, so it may be
     relaid once into the loop and once out.  Compiled here for a described
     v5e (no chip); the step's MoE launches lower in interpret mode.  The
-    gathered view must also reach attention without an f32 copy of it."""
+    gathered view must also reach attention without an f32 copy of it, and
+    MLA's heads attend to the latent itself: no op, fused or not, expands
+    the view to per-head keys or values or copies the rotary key to every
+    head."""
     cfg = _decode_cfg(family)
     params = api.init_params(jax.random.PRNGKey(0), cfg)
     eng = ServingEngine(params, cfg, n_slots=2, max_len=32, kv_block=16,
@@ -251,6 +254,15 @@ def test_decode_step_writes_pool_in_place(one_chip, family):
                     if opcode == "convert" and dims in views and not fused], leaf
         assert any(opcode == "scatter" and dims == shape
                    for _, dims, _, opcode, *_ in ops), f"{leaf}: no row write"
+    if family == "mla":
+        h, t = cfg.n_heads, state["block_tbl"].shape[1] * 16
+        widths = {cfg.mla.qk_nope, cfg.mla.v_dim, cfg.mla.qk_rope,
+                  cfg.mla.qk_nope + cfg.mla.qk_rope}
+        per_head = ({(n, t, h, d) for d in widths}
+                    | {(n, t, h * d) for d in widths}
+                    | {(n * t, h * d) for d in widths})
+        assert not [(name, dims) for name, dims, *_ in ops
+                    if dims in per_head]
 
     # output j (the new state's leaves lead, in key order) aliases the
     # entry parameter that holds the donated state's leaf
